@@ -69,10 +69,7 @@ def _cycles_split(app: str) -> tuple[float, float, float]:
     n_load = float(seg[4, 0])
     seg0 = seg.copy()
     seg0[4, 5] = 0.0
-    import jax.numpy as jnp
-    cyc0, _ = sp._pipeline_jit(jnp.asarray(seg0),
-                               tuple(jnp.asarray(p)
-                                     for p in sp.cfg_scalar_params(None)))
+    cyc0, _ = sp._fold_one(seg0, sp.cfg_scalar_params(None))
     roi = tracegen.scalar_profile_for(app).roi_instr_fraction
     return float(cyc0), n_load, roi
 

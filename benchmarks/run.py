@@ -496,51 +496,35 @@ def serve_rows(quick: bool = False, cache_path: str | None = None,
     return rows
 
 
+# Pallas kernels that the TPU compiler accepts at these shapes; the other
+# six (blackscholes, pathfinder, canneal, particlefilter, decode_attention,
+# ssd_scan) are refused by Mosaic and are pinned as strict xfails in
+# tests/test_tpu_compile.py.
 def kernel_microbench():
+    """Per-kernel device time of the Pallas kernels that compile for the
+    TPU.  A kernel timing from the interpreter says nothing about the chip,
+    so off the TPU this raises instead of falling back."""
+    backend = jax.default_backend()
+    if backend != "tpu":
+        raise RuntimeError(f"kernel_microbench needs a TPU; backend is "
+                           f"{backend!r}")
     from repro.kernels import ops
     k = jax.random.key
     rows = []
-    n = 16384
-    args = (jax.random.uniform(k(0), (n,), jnp.float32, 10, 100),
-            jax.random.uniform(k(1), (n,), jnp.float32, 10, 100),
-            jnp.full((n,), 0.05),
-            jax.random.uniform(k(2), (n,), jnp.float32, 0.1, 0.6),
-            jax.random.uniform(k(3), (n,), jnp.float32, 0.2, 2.0),
-            (jax.random.uniform(k(4), (n,)) > 0.5).astype(jnp.int32))
-    us = _t(lambda *a: ops.blackscholes(*a), *args)
-    rows.append(("kernel_blackscholes", us, f"{n/us:.1f}Mopt_s"))
     a = jax.random.normal(k(5), (258, 512))
     us = _t(lambda x: ops.jacobi2d_step(x, rows_per_block=64), a)
     rows.append(("kernel_jacobi2d", us, f"{a.size/us:.0f}Melem_s"))
-    wall = jax.random.uniform(k(6), (64, 512))
-    us = _t(ops.pathfinder, wall)
-    rows.append(("kernel_pathfinder", us, ""))
     p = jax.random.normal(k(7), (1024, 128))
     c = jax.random.normal(k(8), (512, 128))
     us = _t(ops.streamcluster_dist, p, c)
     gf = 2 * p.shape[0] * c.shape[0] * 128 / us / 1e3
     rows.append(("kernel_streamcluster_dist", us, f"{gf:.2f}GFLOP_s"))
-    u = jax.random.uniform(k(9), (n,), minval=1e-5, maxval=1 - 1e-5)
+    u = jax.random.uniform(k(9), (16384,), minval=1e-5, maxval=1 - 1e-5)
     us = _t(ops.cum_normal_inv, u)
     rows.append(("kernel_swaptions_cni", us, ""))
-    locs = jax.random.randint(k(10), (1024, 2), 0, 1000).astype(jnp.float32)
-    fan = jax.random.randint(k(11), (512, 24), -1, 1024)
-    ca = jax.random.randint(k(12), (512, 2), 0, 1000).astype(jnp.float32)
-    us = _t(lambda *a: ops.canneal_swap_cost(*a), locs, fan, ca, ca)
-    rows.append(("kernel_canneal_swapcost", us, ""))
-    cdf = jnp.sort(jax.random.uniform(k(13), (8192,)))
-    uq = jax.random.uniform(k(14), (1024,))
-    us = _t(ops.particlefilter_findindex, cdf, uq)
-    rows.append(("kernel_pf_findindex", us, ""))
     q = jax.random.normal(k(15), (1, 512, 4, 64), jnp.float32)
     us = _t(lambda q: ops.flash_attention(q, q, q, bq=128, bk=128), q)
     rows.append(("kernel_flash_attention", us, ""))
-    x = jax.random.normal(k(16), (1, 512, 4, 16))
-    dt = jax.nn.softplus(jax.random.normal(k(17), (1, 512, 4)))
-    A = -jnp.exp(jax.random.normal(k(18), (4,)) * 0.3)
-    Bm = jax.random.normal(k(19), (1, 512, 32))
-    us = _t(lambda *a: ops.ssd_scan(*a, chunk=128), x, dt, A, Bm, Bm)
-    rows.append(("kernel_ssd_scan", us, ""))
     return rows
 
 
@@ -624,8 +608,7 @@ def main(argv=None) -> None:
     ap.add_argument("--quick", action="store_true",
                     help="smoke mode: characterization + batched figures + "
                          "frontend cross-validation + a small batched-vs-"
-                         "sequential sweep; skips kernel microbenchmarks and "
-                         "the roofline table.  With --dse: the 384-point "
+                         "sequential sweep; skips the roofline table.  With --dse: the 384-point "
                          "SPACE_QUICK instead of the 1536-point SPACE_FULL")
     ap.add_argument("--dse", action="store_true",
                     help="design-space exploration rows only: enumerate the "
@@ -661,6 +644,9 @@ def main(argv=None) -> None:
                          "wall-clock, scoring throughput, recall@frontier "
                          "vs exhaustive truth, and the held-out-app error "
                          "CDF")
+    ap.add_argument("--kernels", action="store_true",
+                    help="Pallas kernel microbenchmark rows only (the four "
+                         "kernels that compile for the TPU); needs a TPU")
     ap.add_argument("--dse-cache", default=os.path.join(
         os.path.dirname(__file__), "..", "results", "dse_cache.jsonl"),
         help="persistent DSE result cache (JSONL)")
@@ -681,7 +667,11 @@ def main(argv=None) -> None:
              "train/score/recall, scalar-baseline old-vs-new + anchor "
              "scorecard, mechanistic profile scorecard)")
     args = ap.parse_args(argv)
-    if args.surrogate:
+    from repro import compile_cache
+    compile_cache.enable()
+    if args.kernels:
+        fns = (kernel_microbench,)
+    elif args.surrogate:
         fns = (lambda: surrogate_rows(quick=args.quick,
                                       cache_path=args.surrogate_cache),)
     elif args.dse:
@@ -709,7 +699,7 @@ def main(argv=None) -> None:
                sweep_llc, sweep_mshr, frontend_crossval,
                lambda: rvv_rows(), lambda: codegen_rows(),
                steady_state_table, scalar_rows, lambda: profile_rows(),
-               kernel_microbench, roofline_table,
+               roofline_table,
                lambda: sweep_wallclock(quick=False))
     print("name,us_per_call,derived")
     for fn in fns:

@@ -24,7 +24,7 @@ OUT = os.path.join(os.path.dirname(__file__), "..", "tests",
 RTOL = 1e-2  # generous vs float32 platform jitter, tight vs real drift
 
 
-def _payload() -> dict:
+def apps() -> list[str]:
     """All 10 registered apps plus the 10 RVV-assembly-sourced variants
     (trace source: the generated src/repro/asm corpus via repro.core.rvv)
     — 480 cells, up from 408 when the corpus was the hand-written RiVec
@@ -32,10 +32,17 @@ def _payload() -> dict:
     ``:asm`` cells pin the *decoder* end to end: a decode regression that
     survives the crossval mixes still shows up as a speedup drift here."""
     from repro.core import tracegen
-    apps = sorted(tracegen.APPS) + list(tracegen.ASM_APPS)
-    table = suite.sweep_all(apps)
+    return sorted(tracegen.APPS) + list(tracegen.ASM_APPS)
+
+
+def to_payload(table: dict) -> dict:
+    """``suite.sweep_all`` output -> the golden file's layout."""
     return {app: {f"{m}x{l}": round(s, 6) for (m, l), s in grid.items()}
             for app, grid in table.items()}
+
+
+def _payload() -> dict:
+    return to_payload(suite.sweep_all(apps()))
 
 
 def diff_report(got: dict, golden: dict, rtol: float = RTOL) -> list[str]:

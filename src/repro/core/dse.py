@@ -615,5 +615,7 @@ def main(argv=None) -> int:
 if __name__ == "__main__":
     # delegate to the canonical module object: the spaces in repro.configs
     # carry repro.core.dse.DesignSpace instances, not __main__ ones
+    from repro import compile_cache
     from repro.core import dse as _canonical
+    compile_cache.enable()
     raise SystemExit(_canonical.main())

@@ -461,26 +461,27 @@ _profile_jit = jax.jit(_profile_core)
 _SHARDED_JITS: dict[int, object] = {}
 
 
-def _sharded_chunk_jit(ndev: int):
-    """The batched chunk scan sharded over the config axis: an SPMD wrapper
-    around the same vmapped ``_chunk_core``, so each device scans its slice
-    of the batch and results are indistinguishable from the single-device
-    path (the per-lane scan arithmetic is shared).
+def _sharded_chunk_program(devices):
+    """The batched chunk scan sharded over the config axis of ``devices``:
+    an SPMD wrapper around the same vmapped ``_chunk_core``, so each device
+    scans its slice of the batch and results are indistinguishable from the
+    single-device path (the per-lane scan arithmetic is shared)."""
+    from jax.sharding import Mesh, PartitionSpec as P
 
-    Built lazily per device count; ``repro.distributed.sharding`` provides
-    the version-compatible ``shard_map``.
-    """
+    mesh = Mesh(np.asarray(devices), ("cfg",))
+    return jax.jit(jax.shard_map(jax.vmap(_chunk_core), mesh=mesh,
+                                 in_specs=P("cfg"), out_specs=P("cfg")))
+
+
+def _sharded_chunk_jit(ndev: int):
+    """``_sharded_chunk_program`` over the first ``ndev`` local devices,
+    built lazily once per device count."""
     f = _SHARDED_JITS.get(ndev)
     if f is None:
-        from jax.sharding import Mesh, PartitionSpec as P
-
-        from repro.distributed.sharding import compat_shard_map
         # local_devices, not devices: in a multi-process job the mesh must
         # hold only this process's addressable devices
-        mesh = Mesh(np.asarray(jax.local_devices()[:ndev]), ("cfg",))
-        f = _SHARDED_JITS[ndev] = jax.jit(compat_shard_map(
-            jax.vmap(_chunk_core), mesh, in_specs=P("cfg"),
-            out_specs=P("cfg")))
+        f = _SHARDED_JITS[ndev] = _sharded_chunk_program(
+            jax.local_devices()[:ndev])
     return f
 
 
@@ -645,20 +646,16 @@ def trace_len_bucket(n: int) -> int:
 
 
 def jit_cache_size() -> int:
-    """Number of engine executables compiled so far (sequential + batched),
-    or -1 when the installed JAX doesn't expose jit cache introspection
-    (``_cache_size`` is a private API).
+    """Number of engine executables compiled so far (sequential, batched,
+    profiling and sharded).
 
     The batched path's compilation key is (batch bucket, CHUNK) only: flags
     are traced, lengths are chunked, batch sizes are padded to powers of two.
     """
-    try:
-        n = int(_simulate_jit._cache_size() + _chunk_batch_jit._cache_size())
-        n += int(_profile_jit._cache_size())
-        n += sum(int(f._cache_size()) for f in _SHARDED_JITS.values())
-        return n
-    except AttributeError:
-        return -1
+    n = int(_simulate_jit._cache_size() + _chunk_batch_jit._cache_size())
+    n += int(_profile_jit._cache_size())
+    n += sum(int(f._cache_size()) for f in _SHARDED_JITS.values())
+    return n
 
 
 def _run_batch_group(traces: list[isa.Trace], cfgs: list[VectorEngineConfig],

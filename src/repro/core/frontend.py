@@ -65,10 +65,7 @@ import numpy as np
 
 from repro.core import crossval, isa
 
-try:  # the public home since jax 0.4.x; jax.core kept as fallback
-    from jax.extend.core import Literal as _Literal
-except Exception:  # pragma: no cover
-    from jax.core import Literal as _Literal
+from jax.extend.core import Literal as _Literal
 
 
 class FrontendError(Exception):
@@ -89,7 +86,7 @@ FU_OF_PRIM = {
     "shift_left": _S, "shift_right_logical": _S, "shift_right_arithmetic": _S,
     "mul": _M, "integer_pow": _M, "square": _M,
     "div": _D, "sqrt": _D, "rsqrt": _D, "rem": _D,
-    "exp": _T, "exp2": _T, "log": _T, "log2": _T, "log1p": _T, "expm1": _T,
+    "exp": _T, "exp2": _T, "log": _T, "log1p": _T, "expm1": _T,
     "erf": _T, "erfc": _T, "erf_inv": _T, "sin": _T, "cos": _T, "tan": _T,
     "asin": _T, "acos": _T, "atan": _T, "atan2": _T, "sinh": _T, "cosh": _T,
     "tanh": _T, "logistic": _T, "pow": _T, "cbrt": _T,
@@ -110,7 +107,7 @@ SKIP_PRIMS = ("convert_element_type", "broadcast_in_dim", "reshape",
               "squeeze", "expand_dims", "slice", "transpose", "iota",
               "stop_gradient", "copy", "device_put", "bitcast_convert_type")
 
-CALL_PRIMS = ("pjit", "closed_call", "core_call", "custom_jvp_call",
+CALL_PRIMS = ("jit", "pjit", "closed_call", "core_call", "custom_jvp_call",
               "custom_vjp_call", "remat", "checkpoint")
 
 # the contract constants live in the shared cross-validation harness

@@ -9,7 +9,7 @@ it (one of which, particlefilter's 0.104, was documented as non-physical).
 The dynamic instruction stream of an app's scalar ROI is summarized into six
 class segments (simple / mul / div / trans / load / branch) from the app's
 published instruction counts, FU-class mix and its ``ScalarProfile``
-(``tracegen.SCALAR_PROFILES``).  A ``lax.scan`` folds the segments into the
+(``tracegen.SCALAR_PROFILES``).  A float32 fold over the segments fills the
 per-event-kind cycle and count accumulators:
 
   * ``issue``  — issue slots consumed (1/issue_width per instruction;
@@ -23,11 +23,10 @@ per-event-kind cycle and count accumulators:
                  (``mem_stall_cyc`` per load, the fitted profile parameter)
 
 Everything configuration-dependent (``issue_width``, ``branch_miss_penalty``,
-``fusion``, the scalar clock) is a traced parameter, so one compiled scan
-serves every core and the model vmaps over a config axis exactly like the
-vector engine (``scalar_runtime_ns_batch`` is bitwise-equal to the
-sequential path).  The jit key is the (6, 8) segment shape — shared by every
-app — so sweeps never recompile.
+``fusion``, the scalar clock) is a per-row parameter of the fold, which runs
+on the host in numpy over a batch axis (``scalar_runtime_ns_batch`` is
+bitwise-equal to the sequential path: both are the same elementwise
+float32 operations in the same order).
 
 >>> from repro.core import engine as eng
 >>> t2 = scalar_runtime_ns("pathfinder")                  # default dual-issue
@@ -49,8 +48,6 @@ from __future__ import annotations
 
 import functools
 
-import jax
-import jax.numpy as jnp
 import numpy as np
 
 from repro.core import tracegen
@@ -121,17 +118,25 @@ def cfg_scalar_params(cfg=None) -> tuple:
             np.float32(cfg.scalar_freq_ghz))
 
 
-def _scan_core(seg, params):
-    """Fold the segment events into (total cycles, per-kind accumulators)."""
-    issue_w, bmp, fusion_f, _freq = params
+def _fold(seg, params):
+    """Fold the segment events into (total cycles, per-kind accumulators).
 
-    def step(carry, row):
-        cyc, ev = carry
-        count, lat, raw, fusible, bmr, mem, is_br, struct = (
-            row[0], row[1], row[2], row[3], row[4], row[5], row[6], row[7])
+    ``seg`` is ``[B, 6, N_COLS]`` and each of the four ``params`` is ``[B]``
+    (float32).  The fold is 6 rows of elementwise float32 numpy on the host:
+    every operation rounds once, in a fixed order, so a batch of N and N
+    batches of one give the same bits, and no device dispatch is spent on
+    48 numbers."""
+    issue_w, bmp, fusion_f, _freq = (np.asarray(p, np.float32)
+                                     for p in params)
+    seg = np.asarray(seg, np.float32)
+    one = np.float32(1.0)
+    cyc = np.zeros(seg.shape[0], np.float32)
+    ev = np.zeros((seg.shape[0], len(EVENT_KINDS)), np.float32)
+    for r in range(seg.shape[1]):
+        count, lat, raw, fusible, bmr, mem, is_br, struct = seg[:, r].T
         fused = count * fusible * fusion_f        # fused pairs: 1 slot each
         slots = (count - fused) / issue_w
-        stall_lat = jnp.maximum(lat - 1.0, 0.0)
+        stall_lat = np.maximum(lat - one, np.float32(0.0))
         raw_st = count * raw * stall_lat
         struct_st = count * struct * stall_lat
         n_miss = count * bmr
@@ -139,41 +144,34 @@ def _scan_core(seg, params):
         n_hit = count * is_br - n_miss
         mem_st = count * mem
         cyc = cyc + slots + raw_st + struct_st + bmiss_st + mem_st
-        ev = ev + jnp.stack([slots, raw_st, struct_st, n_miss, n_hit,
-                             mem_st, fused])
-        return (cyc, ev), None
-
-    init = (jnp.float32(0.0), jnp.zeros(len(EVENT_KINDS), jnp.float32))
-    (cyc, ev), _ = jax.lax.scan(step, init, seg)
+        ev = ev + np.stack([slots, raw_st, struct_st, n_miss, n_hit,
+                            mem_st, fused], axis=1)
     return cyc, ev
 
 
-_pipeline_jit = jax.jit(_scan_core)
-_pipeline_batch_jit = jax.jit(jax.vmap(_scan_core))
+def _fold_one(seg, params: tuple):
+    """``_fold`` of one ``(6, N_COLS)`` segment array -> (cycles, events)."""
+    cyc, ev = _fold(np.asarray(seg, np.float32)[None],
+                    tuple(np.asarray([p], np.float32) for p in params))
+    return cyc[0], ev[0]
 
 
 def scalar_cycles(app_name: str, cfg=None) -> float:
     """Total modeled scalar-core cycles of the app's scalar-version ROI."""
-    cyc, _ = _pipeline_jit(jnp.asarray(segments_for(app_name)),
-                           tuple(jnp.asarray(p)
-                                 for p in cfg_scalar_params(cfg)))
-    return float(cyc)
+    return float(_fold_one(segments_for(app_name), cfg_scalar_params(cfg))[0])
 
 
 def scalar_events(app_name: str, cfg=None) -> dict:
     """Per-event-kind accumulators (cycles for stall kinds, counts for
     ``bmiss``/``bhit``/``fused``) — the scorecard's breakdown view."""
-    _, ev = _pipeline_jit(jnp.asarray(segments_for(app_name)),
-                          tuple(jnp.asarray(p)
-                                for p in cfg_scalar_params(cfg)))
+    _, ev = _fold_one(segments_for(app_name), cfg_scalar_params(cfg))
     return dict(zip(EVENT_KINDS, (float(v) for v in ev)))
 
 
 @functools.lru_cache(maxsize=None)
 def _runtime_cached(base_app: str, params: tuple) -> float:
-    cyc, _ = _pipeline_jit(jnp.asarray(segments_for(base_app)),
-                           tuple(jnp.asarray(p) for p in params))
-    return float(cyc) / float(params[3])
+    return float(_fold_one(segments_for(base_app), params)[0]) \
+        / float(params[3])
 
 
 def scalar_runtime_ns(app_name: str, cfg=None) -> float:
@@ -188,18 +186,17 @@ def scalar_runtime_ns(app_name: str, cfg=None) -> float:
 
 def scalar_runtime_ns_batch(apps, cfgs) -> list[float]:
     """Batched ``scalar_runtime_ns``: N (app, config) pairs through one
-    vmapped scan dispatch.  Bitwise-equal to the sequential path (the scan
-    core is shared; ``--check`` asserts it)."""
+    fold.  Bitwise-equal to the sequential path (the fold is shared;
+    ``--check`` asserts it)."""
     if len(apps) != len(cfgs):
         raise ValueError(f"{len(apps)} apps vs {len(cfgs)} configs")
     if not apps:
         return []
-    segs = jnp.asarray(np.stack([segments_for(a) for a in apps]))
+    segs = np.stack([segments_for(a) for a in apps])
     cols = list(zip(*(cfg_scalar_params(c) for c in cfgs)))
-    params = tuple(jnp.asarray(np.stack(col)) for col in cols)
-    cyc, _ = _pipeline_batch_jit(segs, params)
+    cyc, _ = _fold(segs, cols)
     freqs = np.asarray(cols[3], np.float32)
-    return [float(c) / float(f) for c, f in zip(np.asarray(cyc), freqs)]
+    return [float(c) / float(f) for c, f in zip(cyc, freqs)]
 
 
 # --------------------------------------------------------------------------

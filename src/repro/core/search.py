@@ -379,6 +379,10 @@ def _verify_exact(res: SearchResult, cache: dse.ResultCache,
     return checked
 
 
+# --smoke bound on the fit-set median relative error (0.3 % on the CPU)
+SMOKE_FIT_P50 = 0.05
+
+
 def main(argv=None) -> int:
     import argparse
     import time
@@ -394,8 +398,9 @@ def main(argv=None) -> int:
     ap.add_argument("--smoke", action="store_true",
                     help="CI gate: train on a 64-point explore, search the "
                          "18k-point space, assert every frontier point is "
-                         "exact-verified and repeat runs (both scoring "
-                         "modes) are bitwise-identical")
+                         "exact-verified, repeat runs (both scoring "
+                         "modes) are bitwise-identical and the fit-set "
+                         "median error is within SMOKE_FIT_P50")
     args = ap.parse_args(argv)
     apps = tuple(args.apps.split(","))
     train_space = {"smoke": vcfg.SPACE_SMOKE, "quick": vcfg.SPACE_QUICK,
@@ -441,14 +446,19 @@ def main(argv=None) -> int:
     for e in evo:
         _verify_exact(e, cache)
     fpe1, fpe2 = (frontier_fingerprint(e) for e in evo)
-    ok = fp1 == fp2 and fpe1 == fpe2
+    # a surrogate that cannot fit its own training rows nominates at random
+    fit_ok = card["rel_err_p50"] <= SMOKE_FIT_P50
+    ok = fp1 == fp2 and fpe1 == fpe2 and fit_ok
     print(f"repeat: exhaustive {'bitwise-identical' if fp1 == fp2 else 'DIVERGED'}"
           f" ({fp1}); evolutionary "
-          f"{'bitwise-identical' if fpe1 == fpe2 else 'DIVERGED'} ({fpe1}) "
+          f"{'bitwise-identical' if fpe1 == fpe2 else 'DIVERGED'} ({fpe1}); "
+          f"fit-set p50 {'<=' if fit_ok else '>'} {SMOKE_FIT_P50:.0%} "
           f"-> {'ok' if ok else 'FAIL'}")
     return 0 if ok else 1
 
 
 if __name__ == "__main__":
+    from repro import compile_cache
     from repro.core import search as _canonical
+    compile_cache.enable()
     raise SystemExit(_canonical.main())

@@ -41,6 +41,7 @@ True
 """
 from __future__ import annotations
 
+import functools
 from dataclasses import dataclass, fields as _dc_fields
 
 import numpy as np
@@ -206,7 +207,10 @@ class Surrogate:
 
 
 def _standardize(X, mean, std):
-    return (jnp.log1p(jnp.asarray(X)) - mean) / std
+    # multiply by a host-side reciprocal: jit rewrites a divide by a
+    # constant into exactly this, so eager and jitted callers (the row path
+    # and SpaceScorer) round alike
+    return (jnp.log1p(jnp.asarray(X)) - mean) * np.reciprocal(std)
 
 
 # log-runtime predictions are clamped to a generous physical band before
@@ -216,9 +220,11 @@ _LOG_CLIP = (0.0, 50.0)
 
 
 def _forward(params, X):
-    h = jax.nn.relu(X @ params["w1"] + params["b1"])
-    h = jax.nn.relu(h @ params["w2"] + params["b2"])
-    return (h @ params["w3"] + params["b3"])[:, 0]
+    # float32 matmuls on every backend (the TPU's default is one bf16 pass)
+    mm = functools.partial(jnp.matmul, precision=jax.lax.Precision.HIGHEST)
+    h = jax.nn.relu(mm(X, params["w1"]) + params["b1"])
+    h = jax.nn.relu(mm(h, params["w2"]) + params["b2"])
+    return (mm(h, params["w3"]) + params["b3"])[:, 0]
 
 
 _forward_jit = jax.jit(_forward)
@@ -250,16 +256,20 @@ def fit(rows, hidden: int = 64, steps: int = 1500, lr: float = 3e-3,
         raise ValueError("fit() needs at least one training row")
     X = np.stack([row_features(r["app"], r["cfg"]) for r in rows])
     y = np.log(np.asarray([r["runtime_ns"] for r in rows], np.float32))
-    Xl = np.log1p(X)
+    Xl = np.log1p(X.astype(np.float64))
     mean = Xl.mean(axis=0)
     # Features constant across the training rows (a knob the mined sweep
     # never varied) get std=1, NOT a tiny floor: they standardize to ~0 in
     # training so the model ignores them, and stay bounded when the search
     # space later sweeps them — a 1e-6 floor would turn any unseen choice
-    # into a +-10^5 activation and a nonsense (inf) prediction.
+    # into a +-10^5 activation and a nonsense (inf) prediction.  The stats
+    # are float64, where a constant column's std is exactly 0: in float32
+    # its mean rounds, its std lands just above 1e-6, and the column then
+    # multiplies a one-ulp difference between the host's log1p and the
+    # device's (the TPU's) by ~10^6.
     std = Xl.std(axis=0)
     std = np.where(std < 1e-6, 1.0, std)
-    Xn = jnp.asarray((Xl - mean) / std)
+    Xn = jnp.asarray(((Xl - mean) / std).astype(np.float32))
     yj = jnp.asarray(y)
 
     opt_cfg = optimizer.OptConfig(

@@ -398,4 +398,6 @@ def main(argv=None) -> int:
 
 
 if __name__ == "__main__":
+    from repro import compile_cache
+    compile_cache.enable()
     raise SystemExit(main())

@@ -16,7 +16,6 @@ import jax
 import jax.numpy as jnp
 from jax.sharding import PartitionSpec as P
 
-from repro.distributed.sharding import compat_shard_map
 
 
 def _dp_axes(mesh, batch):
@@ -86,7 +85,7 @@ def flash_decode_attention(q, cache_k, cache_v, k_new, v_new, pos, mesh):
         o = jax.lax.psum(o, "model") / jnp.maximum(l, 1e-30).transpose(0, 2, 1)[..., None]
         return o.astype(q.dtype), ck, cv
 
-    out, ck, cv = compat_shard_map(
+    out, ck, cv = jax.shard_map(
         body, mesh=mesh,
         in_specs=(P(dp_spec, None, None, None),
                   P(dp_spec, "model", None, None),

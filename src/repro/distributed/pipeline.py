@@ -13,7 +13,6 @@ import jax
 import jax.numpy as jnp
 from jax.sharding import PartitionSpec as P
 
-from repro.distributed.sharding import compat_shard_map
 
 
 def pipeline_apply(fn_stage, params_stages, x_micro, mesh, *, stages: int):
@@ -58,7 +57,7 @@ def pipeline_apply(fn_stage, params_stages, x_micro, mesh, *, stages: int):
         out = jax.lax.psum(out, "pod") / 1.0  # all pods but last contribute 0
         return out
 
-    return compat_shard_map(
+    return jax.shard_map(
         sharded, mesh=mesh,
         in_specs=(P(), P("pod")),
         out_specs=P(),

@@ -137,22 +137,6 @@ def tree_constraint(tree, logical_tree, mesh, rules=None):
     return jax.tree.unflatten(tdef, out)
 
 
-# --- version compat ----------------------------------------------------------
-
-def compat_shard_map(f, mesh, in_specs, out_specs, check_vma: bool = True):
-    """``jax.shard_map`` on any installed JAX: newer releases expose it at the
-    top level with a ``check_vma`` flag, older ones only have
-    ``jax.experimental.shard_map.shard_map`` with the equivalent flag spelled
-    ``check_rep``."""
-    sm = getattr(jax, "shard_map", None)
-    if sm is not None:
-        return sm(f, mesh=mesh, in_specs=in_specs, out_specs=out_specs,
-                  check_vma=check_vma)
-    from jax.experimental.shard_map import shard_map as sm_exp
-    return sm_exp(f, mesh=mesh, in_specs=in_specs, out_specs=out_specs,
-                  check_rep=check_vma)
-
-
 # --- active-mesh context -----------------------------------------------------
 # Model code calls constraint(x, logical) without threading a mesh through every
 # layer; the step builders (train/serve/dryrun) install the mesh here.  When no

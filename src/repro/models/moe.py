@@ -13,7 +13,7 @@ from __future__ import annotations
 import jax
 import jax.numpy as jnp
 
-from repro.distributed.sharding import compat_shard_map, constraint
+from repro.distributed.sharding import constraint
 from repro.models import layers as L
 from repro.models.layers import PD
 
@@ -153,7 +153,7 @@ def moe_fwd(p, h, cfg):
     else:  # w1/w3 are [E, D, F], w2 is [E, F, D]: shard the F dim of each
         w13_spec = P_(None, None, "model")
         w2_spec = P_(None, "model", None)
-    out, aux = compat_shard_map(
+    out, aux = jax.shard_map(
         body, mesh=mesh,
         in_specs=(P_(dp_spec, seq_model, None), P_(None, None),
                   w13_spec, w13_spec, w2_spec),

@@ -246,8 +246,7 @@ class SimService:
                     [c.body for c in batch], [c.cfg for c in batch],
                     warmup=self.warmup, measure=self.measure)
                 jc1 = eng.jit_cache_size()
-                if jc0 >= 0 and jc1 >= 0:
-                    self.recompiles += jc1 - jc0
+                self.recompiles += jc1 - jc0
                 self.n_batches += 1
                 batch_id = self.n_batches
                 t_done = self.clock()
@@ -519,5 +518,7 @@ def main(argv=None) -> int:
 
 
 if __name__ == "__main__":
+    from repro import compile_cache
     from repro.serve import sim_service as _canonical
+    compile_cache.enable()
     raise SystemExit(_canonical.main())

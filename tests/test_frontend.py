@@ -1,9 +1,12 @@
 """Jaxpr→vector-IR frontend: lowering unit tests + the cross-validation
 contract (derived bodies vs hand-coded tracegen bodies) + the three
 frontend-only ML workloads."""
+import jax
 import jax.numpy as jnp
+import jax.scipy.special as jsp
 import numpy as np
 import pytest
+from jax import lax
 
 from repro.core import engine as eng
 from repro.core import frontend as fe
@@ -58,6 +61,117 @@ def test_cumsum_expands_to_slide_add_ladder():
                                        ins=(fe.Stream("a", 8.0),))])
     k = _kinds(tr)
     assert k["slide"] == 6 and k["arith"] == 6   # ceil(log2(64)) rounds
+
+
+# How a kernel spells each primitive of the frontend's tables in jnp (lax
+# where jnp has no spelling that reaches the primitive).  A JAX release that
+# renames a primitive, or wraps it in a new call primitive, fails its case
+# here instead of failing every app that happens to use it.
+def _i32(x):
+    return x.astype(jnp.int32)
+
+
+PRIM_SPELLING = {
+    "add": lambda a, b: a + b,
+    "add_any": lambda a, b: jax.vmap(jax.grad(lambda x: x * x + x))(a),
+    "sub": lambda a, b: a - b,
+    "max": jnp.maximum,
+    "min": jnp.minimum,
+    "neg": lambda a, b: -a,
+    "abs": lambda a, b: jnp.abs(a),
+    "and": lambda a, b: (a > 0) & (b > 0),
+    "or": lambda a, b: (a > 0) | (b > 0),
+    "xor": lambda a, b: (a > 0) ^ (b > 0),
+    "not": lambda a, b: ~(a > 0),
+    "gt": lambda a, b: a > b,
+    "lt": lambda a, b: a < b,
+    "ge": lambda a, b: a >= b,
+    "le": lambda a, b: a <= b,
+    "eq": lambda a, b: a == b,
+    "ne": lambda a, b: a != b,
+    "select_n": lambda a, b: jnp.where(a > b, a, b),
+    "sign": lambda a, b: jnp.sign(a),
+    "floor": lambda a, b: jnp.floor(a),
+    "ceil": lambda a, b: jnp.ceil(a),
+    "round": lambda a, b: jnp.round(a),
+    "clamp": lambda a, b: lax.clamp(0.0, a, 1.0),
+    "is_finite": lambda a, b: jnp.isfinite(a),
+    "shift_left": lambda a, b: jnp.left_shift(_i32(a), 1),
+    "shift_right_logical":
+        lambda a, b: jnp.right_shift(a.astype(jnp.uint32), 1),
+    "shift_right_arithmetic": lambda a, b: jnp.right_shift(_i32(a), 1),
+    "mul": lambda a, b: a * b,
+    "integer_pow": lambda a, b: a ** 3,
+    "square": lambda a, b: jnp.square(a),
+    "div": lambda a, b: a / b,
+    "sqrt": lambda a, b: jnp.sqrt(a),
+    "rsqrt": lambda a, b: lax.rsqrt(a),
+    "rem": lambda a, b: jnp.fmod(a, b),
+    "exp": lambda a, b: jnp.exp(a),
+    "exp2": lambda a, b: jnp.exp2(a),
+    "log": lambda a, b: jnp.log(a),
+    "log1p": lambda a, b: jnp.log1p(a),
+    "expm1": lambda a, b: jnp.expm1(a),
+    "erf": lambda a, b: jsp.erf(a),
+    "erfc": lambda a, b: jsp.erfc(a),
+    "erf_inv": lambda a, b: jsp.erfinv(a),
+    "sin": lambda a, b: jnp.sin(a),
+    "cos": lambda a, b: jnp.cos(a),
+    "tan": lambda a, b: jnp.tan(a),
+    "asin": lambda a, b: jnp.arcsin(a),
+    "acos": lambda a, b: jnp.arccos(a),
+    "atan": lambda a, b: jnp.arctan(a),
+    "atan2": jnp.arctan2,
+    "sinh": lambda a, b: jnp.sinh(a),
+    "cosh": lambda a, b: jnp.cosh(a),
+    "tanh": lambda a, b: jnp.tanh(a),
+    "logistic": lambda a, b: jax.nn.sigmoid(a),
+    "pow": jnp.power,
+    "cbrt": lambda a, b: jnp.cbrt(a),
+    "reduce_sum": lambda a, b: jnp.sum(a),
+    "reduce_max": lambda a, b: jnp.max(a),
+    "reduce_min": lambda a, b: jnp.min(a),
+    "reduce_prod": lambda a, b: jnp.prod(a),
+    "cumsum": lambda a, b: jnp.cumsum(a),
+    "cummax": lambda a, b: lax.cummax(a),
+    "cummin": lambda a, b: lax.cummin(a),
+    "cumprod": lambda a, b: jnp.cumprod(a),
+    "cumlogsumexp": lambda a, b: lax.cumlogsumexp(a),
+}
+
+
+def _primitives(jaxpr, out):
+    """Every primitive name in ``jaxpr``, nested call bodies included."""
+    for eqn in jaxpr.eqns:
+        out.add(eqn.primitive.name)
+        for p in eqn.params.values():
+            for sub in (p if isinstance(p, (list, tuple)) else (p,)):
+                inner = getattr(sub, "jaxpr", sub)
+                if hasattr(inner, "eqns"):
+                    _primitives(inner, out)
+    return out
+
+
+def test_every_table_primitive_has_a_spelling():
+    assert set(PRIM_SPELLING) == (set(fe.FU_OF_PRIM) | set(fe.REDUCE_FU)
+                                  | set(fe.CUMULATIVE_FU))
+
+
+@pytest.mark.parametrize("prim", sorted(PRIM_SPELLING))
+def test_table_primitive_lowers_on_installed_jax(prim):
+    fn = PRIM_SPELLING[prim]
+    x = jnp.ones(16, jnp.float32)
+    assert prim in _primitives(jax.make_jaxpr(fn)(x, x).jaxpr, set())
+    tr = fe.lower_trace([fe.KernelBody(fn, 16, ins=(fe.Stream("a", 8.0),
+                                                    fe.Stream("b", 8.0)))])
+    if prim in fe.REDUCE_FU:
+        kind, fu = isa.VREDUCE, fe.REDUCE_FU[prim]
+    else:
+        kind = isa.VARITH
+        fu = fe.FU_OF_PRIM.get(prim, fe.CUMULATIVE_FU.get(prim))
+    assert np.any((tr.kind == kind) & (tr.fu == fu)), prim
+    if prim in fe.CUMULATIVE_FU:
+        assert np.any(tr.kind == isa.VSLIDE), prim
 
 
 def test_gather_becomes_indexed_load_with_stream_footprint():

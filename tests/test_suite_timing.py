@@ -3,7 +3,6 @@ scalar-pipeline model's unit tier (event accounting, knob monotonicity,
 batched bitwise equivalence)."""
 import dataclasses
 
-import jax.numpy as jnp
 import numpy as np
 import pytest
 
@@ -27,9 +26,7 @@ def test_anchor_speedups(app, mvl, lanes, target, kind):
 # ------------------------------------------------- scalar-pipeline unit tier
 
 def _cycles(seg, cfg=None):
-    cyc, _ = sp._pipeline_jit(jnp.asarray(np.asarray(seg, np.float32)),
-                              tuple(jnp.asarray(p)
-                                    for p in sp.cfg_scalar_params(cfg)))
+    cyc, _ = sp._fold_one(seg, sp.cfg_scalar_params(cfg))
     return float(cyc)
 
 

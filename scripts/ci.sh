@@ -17,7 +17,8 @@ python -m pytest --doctest-modules -q -p no:randomly \
   src/repro/core/memory.py src/repro/core/suite.py src/repro/core/dse.py \
   src/repro/core/codegen.py src/repro/serve/sim_service.py \
   src/repro/core/surrogate.py src/repro/core/search.py \
-  src/repro/core/scalar_pipeline.py src/repro/core/telemetry.py
+  src/repro/core/scalar_pipeline.py src/repro/core/telemetry.py \
+  src/repro/core/registry.py
 
 echo "== docs gate: README snippets =="
 # extract EVERY ```python fenced block from the README and execute them in

@@ -37,7 +37,6 @@ from __future__ import annotations
 import itertools
 import json
 import os
-import time
 import warnings
 from dataclasses import dataclass, fields
 
@@ -49,7 +48,7 @@ except ImportError:          # non-POSIX: advisory locking degrades to none
     fcntl = None
 
 from repro.core import engine as eng
-from repro.core import isa, tracegen
+from repro.core import isa, telemetry, tracegen
 
 _CFG_FIELDS = {f.name: f for f in fields(eng.VectorEngineConfig)}
 
@@ -435,47 +434,58 @@ def explore(space, apps=None, cache: ResultCache | None = None,
 
     h0, m0 = cache.hits, cache.misses
     model_fp = eng.model_fingerprint()
-    t_key0 = time.perf_counter()
-    cells = []                       # (app, cfg, body, key)
-    need: dict[str, tuple] = {}      # first (body, cfg) per missing key
-    for app in apps:
-        for cfg in cfgs:
-            body, key = cell_key(app, cfg, warmup, measure,
-                                 model_fp=model_fp)
-            cells.append((app, cfg, body, key))
-            if cache.get(key) is None and key not in need:
-                need[key] = (body, cfg)
-    t_key1 = t_disp1 = time.perf_counter()
-    if need:
-        times = eng.steady_state_time_batch(
-            [b for b, _ in need.values()], [c for _, c in need.values()],
-            warmup=warmup, measure=measure)
-        for key, t in zip(need, times):
-            cache.put(key, t)
-        cache.flush()
-        t_disp1 = time.perf_counter()
+    at_start = telemetry.totals()
+    with telemetry.span("dse.key") as key_span:
+        cells = []                       # (app, cfg, body, key)
+        need: dict[str, tuple] = {}      # first (body, cfg) per missing key
+        for app in apps:
+            for cfg in cfgs:
+                body, key = cell_key(app, cfg, warmup, measure,
+                                     model_fp=model_fp)
+                cells.append((app, cfg, body, key))
+                if cache.get(key) is None and key not in need:
+                    need[key] = (body, cfg)
+    at_dispatch = telemetry.totals()
+    with telemetry.span("dse.dispatch") as disp_span:
+        if need:
+            times = eng.steady_state_time_batch(
+                [b for b, _ in need.values()], [c for _, c in need.values()],
+                warmup=warmup, measure=measure)
+            for key, t in zip(need, times):
+                cache.put(key, t)
+            cache.flush()
+    in_dispatch = telemetry.since(at_dispatch)["spans"]
 
-    records = []
-    for app, cfg, body, key in cells:
-        per_chunk = cache._mem[key]
-        runtime = suite.vector_runtime_from_per_chunk(app, cfg, body,
-                                                      per_chunk)
-        records.append(DseRecord(
-            app=app, label=cfg.label(), cfg=cfg, steady_ns=per_chunk,
-            runtime_ns=runtime,
-            speedup=suite.scalar_runtime_ns(app, cfg) / runtime,
-            area_kb=area_proxy_kb(cfg)))
-    t_derive1 = time.perf_counter()
+    with telemetry.span("dse.derive") as derive_span:
+        records = []
+        for app, cfg, body, key in cells:
+            per_chunk = cache._mem[key]
+            runtime = suite.vector_runtime_from_per_chunk(app, cfg, body,
+                                                          per_chunk)
+            records.append(DseRecord(
+                app=app, label=cfg.label(), cfg=cfg, steady_ns=per_chunk,
+                runtime_ns=runtime,
+                speedup=suite.scalar_runtime_ns(app, cfg) / runtime,
+                area_kb=area_proxy_kb(cfg)))
+    telemetry.count("dse.cells", len(records))
+    counters = telemetry.since(at_start)["counters"]
+    telemetry.record(telemetry.snapshot_row(
+        "dse.study", space=name, cells=len(records), counters=counters))
     lookups = (cache.hits - h0) + (cache.misses - m0)
-    from repro.core import telemetry
     phases = [
-        telemetry.snapshot_row("dse.phase", phase="key", wall_s=t_key1 - t_key0,
+        telemetry.snapshot_row("dse.phase", phase="key",
+                               wall_s=key_span.wall_s,
                                cells=len(cells), misses=len(need)),
         telemetry.snapshot_row("dse.phase", phase="dispatch",
-                               wall_s=t_disp1 - t_key1, simulated=len(need)),
+                               wall_s=disp_span.wall_s, simulated=len(need),
+                               self_s=in_dispatch["dse.dispatch"]["self_s"]),
         telemetry.snapshot_row("dse.phase", phase="derive",
-                               wall_s=t_derive1 - t_disp1,
+                               wall_s=derive_span.wall_s,
                                records=len(records)),
+    ] + [
+        telemetry.snapshot_row("dse.phase", phase=k, wall_s=v["total_s"],
+                               calls=v["calls"], parent="dispatch")
+        for k, v in sorted(in_dispatch.items()) if k.startswith("engine.")
     ]
     stats = {
         "lookups": lookups,
@@ -485,6 +495,7 @@ def explore(space, apps=None, cache: ResultCache | None = None,
         "hit_rate": (lookups - len(need)) / lookups if lookups else 0.0,
         "devices": _device_count(),
         "phases": phases,
+        "counters": counters,
     }
     return DseResult(space=name, apps=apps, n_configs=len(cfgs),
                      records=records, stats=stats)

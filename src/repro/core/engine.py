@@ -40,6 +40,7 @@ import numpy as np
 
 from repro.core import isa
 from repro.core import memory
+from repro.core import registry
 
 MAX_RING = 64  # static ring-buffer capacity (>= max rob/queue/phys-in-flight)
 
@@ -426,8 +427,11 @@ def _init_carry_stats():
                             jnp.zeros(4, jnp.float32))
 
 
+# Each scan program counts one ``engine.traces`` in its Python body, which
+# runs once per jit cache miss (``jit_cache_size``).
 def _scan_core(xs, params):
     """One trace x one config, full-length scan -> timing dict."""
+    registry.count("engine.traces")
     carry, _ = jax.lax.scan(_make_step(params), _init_carry(), xs)
     return _metrics(carry)
 
@@ -436,6 +440,7 @@ def _profile_core(xs, params):
     """The collect_stats scan: same step arithmetic plus the attribution
     accumulators and per-record timeline outputs.  One extra jit key total
     (``_profile_jit``); pure jnp, so it vmaps like the default core."""
+    registry.count("engine.traces")
     carry, ys = jax.lax.scan(_make_step(params, collect=True),
                              _init_carry_stats(), xs)
     out = _metrics(carry)
@@ -448,7 +453,9 @@ def _chunk_core(carry, xs, params):
     """One fixed-size chunk of the scan, resumable: threading the carry
     through repeated calls is exactly the full scan, but every trace length
     reuses the same (batch, CHUNK)-shaped executable instead of compiling
-    per length — the jit-cache memoization that makes repeat sweeps cheap."""
+    per length — the jit-cache memoization that makes repeat sweeps cheap.
+    The sharded program traces it too, so its count covers both."""
+    registry.count("engine.traces")
     carry, _ = jax.lax.scan(_make_step(params), carry, xs)
     return carry
 
@@ -647,15 +654,14 @@ def trace_len_bucket(n: int) -> int:
 
 def jit_cache_size() -> int:
     """Number of engine executables compiled so far (sequential, batched,
-    profiling and sharded).
+    profiling and sharded): the ``engine.traces`` counter, which each scan
+    program's Python body adds to once per jit cache miss.  An
+    ahead-of-time ``.lower()`` of one of them counts as well.
 
     The batched path's compilation key is (batch bucket, CHUNK) only: flags
     are traced, lengths are chunked, batch sizes are padded to powers of two.
     """
-    n = int(_simulate_jit._cache_size() + _chunk_batch_jit._cache_size())
-    n += int(_profile_jit._cache_size())
-    n += sum(int(f._cache_size()) for f in _SHARDED_JITS.values())
-    return n
+    return int(registry.totals()["counters"].get("engine.traces", 0))
 
 
 def _run_batch_group(traces: list[isa.Trace], cfgs: list[VectorEngineConfig],
@@ -673,28 +679,41 @@ def _run_batch_group(traces: list[isa.Trace], cfgs: list[VectorEngineConfig],
     """
     b = len(traces)
     bb = _pow2_bucket(b)
-    stacked = isa.stack_traces(traces + [traces[0]] * (bb - b), length)
-    xs_np = [getattr(stacked, f) for f in _TRACE_FIELDS]
-    cols = list(zip(*(_cfg_params_np(c) for c in (cfgs + [cfgs[0]] * (bb - b)))))
-    params = tuple(jnp.asarray(np.stack(col)) for col in cols)
-    carry = jax.tree.map(
-        lambda a: jnp.zeros((bb,) + a.shape, a.dtype), _init_carry())
+    n_chunks = length // CHUNK
+    with registry.span("engine.stack"):
+        stacked = isa.stack_traces(traces + [traces[0]] * (bb - b), length)
+        xs_np = [getattr(stacked, f) for f in _TRACE_FIELDS]
+        cols = list(zip(*(_cfg_params_np(c)
+                          for c in (cfgs + [cfgs[0]] * (bb - b)))))
+        params = tuple(jnp.asarray(np.stack(col)) for col in cols)
+        carry = jax.tree.map(
+            lambda a: jnp.zeros((bb,) + a.shape, a.dtype), _init_carry())
     times, busy_l, busy_v = [], [], []
-    for i in range(length // CHUNK):
-        xs = tuple(jnp.asarray(a[:, i * CHUNK:(i + 1) * CHUNK]) for a in xs_np)
-        carry = _dispatch_chunk_batch(carry, xs, params, bb)
-        if collect_times:
-            times.append(jnp.maximum(carry[9], carry[14]))
-            busy_l.append(carry[16])
-            busy_v.append(carry[17])
-    out = {k: np.asarray(v) for k, v in _metrics(carry).items()}
-    rows = [{k: float(v[i]) for k, v in out.items()} for i in range(b)]
-    if collect_times:
+    for i in range(n_chunks):
+        with registry.span("engine.copy"):
+            xs = tuple(jnp.asarray(a[:, i * CHUNK:(i + 1) * CHUNK])
+                       for a in xs_np)
+        with registry.span("engine.launch"):
+            carry = _dispatch_chunk_batch(carry, xs, params, bb)
+            if collect_times:
+                times.append(jnp.maximum(carry[9], carry[14]))
+                busy_l.append(carry[16])
+                busy_v.append(carry[17])
+    # the reads below would wait anyway: this splits the wait from them
+    with registry.span("engine.wait"):
+        jax.block_until_ready((carry, times, busy_l, busy_v))
+    registry.count("engine.launches", n_chunks)
+    registry.count("engine.lane_steps_scanned", bb * length)
+    registry.count("engine.lane_steps_batch_pad", (bb - b) * length)
+    with registry.span("engine.readback"):
+        out = {k: np.asarray(v) for k, v in _metrics(carry).items()}
+        rows = [{k: float(v[i]) for k, v in out.items()} for i in range(b)]
+        if not collect_times:
+            return rows
         return (rows,
                 np.stack([np.asarray(t) for t in times]),
                 np.stack([np.asarray(t) for t in busy_l]),
                 np.stack([np.asarray(t) for t in busy_v]))
-    return rows
 
 
 def _broadcast_pairs(traces, cfgs, noun: str = "traces"):
@@ -734,6 +753,8 @@ def simulate_batch(traces, cfgs) -> list[dict]:
     for length, idxs in sorted(_group_by_length_bucket(traces).items()):
         outs = _run_batch_group([traces[i] for i in idxs],
                                 [cfgs[i] for i in idxs], length)
+        registry.count("engine.lane_steps_real",
+                       sum(len(traces[i]) for i in idxs))
         for i, r in zip(idxs, outs):
             results[i] = r
     return results
@@ -770,30 +791,36 @@ def steady_state_time_batch(bodies, cfgs, warmup: int = 8,
     if not bodies:
         return []
     traces, w_chunks = [], []
-    for body in bodies:
-        warm = body.tile(warmup)
-        wlen = _len_bucket(len(warm))
-        traces.append(warm.pad_to(wlen).concat(body.tile(measure)))
-        w_chunks.append(wlen // CHUNK)
+    with registry.span("engine.build"):
+        for body in bodies:
+            warm = body.tile(warmup)
+            wlen = _len_bucket(len(warm))
+            traces.append(warm.pad_to(wlen).concat(body.tile(measure)))
+            w_chunks.append(wlen // CHUNK)
     out: list = [0.0] * len(traces)
     for length, idxs in sorted(_group_by_length_bucket(traces).items()):
         rows, times, busy_l, busy_v = _run_batch_group(
             [traces[i] for i in idxs], [cfgs[i] for i in idxs], length,
             collect_times=True)
-        for lane, i in enumerate(idxs):
-            t1 = float(times[w_chunks[i] - 1, lane])
-            steady = (rows[lane]["time"] - t1) / measure
-            if not with_util:
-                out[i] = steady
-                continue
-            wall = max(rows[lane]["time"] - t1, 1e-9)
-            out[i] = {
-                "steady_ns": steady,
-                "lane_util": (rows[lane]["lane_busy"]
-                              - float(busy_l[w_chunks[i] - 1, lane])) / wall,
-                "vmu_util": (rows[lane]["vmu_busy"]
-                             - float(busy_v[w_chunks[i] - 1, lane])) / wall,
-            }
+        registry.count("engine.lane_steps_real",
+                       (warmup + measure) * sum(len(bodies[i]) for i in idxs))
+        with registry.span("engine.readback"):
+            for lane, i in enumerate(idxs):
+                t1 = float(times[w_chunks[i] - 1, lane])
+                steady = (rows[lane]["time"] - t1) / measure
+                if not with_util:
+                    out[i] = steady
+                    continue
+                wall = max(rows[lane]["time"] - t1, 1e-9)
+                out[i] = {
+                    "steady_ns": steady,
+                    "lane_util": (rows[lane]["lane_busy"]
+                                  - float(busy_l[w_chunks[i] - 1, lane]))
+                    / wall,
+                    "vmu_util": (rows[lane]["vmu_busy"]
+                                 - float(busy_v[w_chunks[i] - 1, lane]))
+                    / wall,
+                }
     return out
 
 

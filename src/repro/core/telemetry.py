@@ -1,6 +1,6 @@
 """Unified telemetry: cycle attribution, timelines, latency histograms.
 
-Three consumers share the schema defined here (``SCHEMA`` rows produced by
+Four consumers share one schema (``SCHEMA`` rows produced by
 :func:`snapshot_row`):
 
 * **Engine profiling** — ``engine.simulate(..., collect_stats=True)`` returns
@@ -11,10 +11,15 @@ Three consumers share the schema defined here (``SCHEMA`` rows produced by
   and a Chrome Trace Event Format timeline (:func:`chrome_trace`) loadable in
   ``chrome://tracing`` / https://ui.perfetto.dev.
 * **Serving** — ``repro.serve.sim_service`` records request latencies into a
-  :class:`LatencyHistogram` (bounded, log-spaced) and emits periodic
-  ``snapshot_row`` stats.
+  :class:`LatencyHistogram` (bounded, log-spaced) and counts each cell's
+  queue wait.
 * **DSE / search** — ``repro.core.dse.explore`` and ``repro.core.search``
   log per-phase wall-clock + cache-counter rows in the same shape.
+* **Host spans and counters** — :func:`span`, :func:`count`,
+  :func:`totals` (``repro.core.registry``, re-exported here): the engine
+  batch path, ``dse.explore`` and the service time their host work and
+  count their scanned lane-steps and launches in one process-wide
+  registry; ``docs/observability.md`` lists the names.
 
 The module-stress classification here is the *mechanistic* twin of
 ``benchmarks/module_stress.py``'s differential (knob-ablation) matrix; the
@@ -39,13 +44,10 @@ import numpy as np
 
 from repro.core import engine as eng
 from repro.core import isa
-
-SCHEMA = "repro.telemetry/v1"
-
-
-def snapshot_row(kind: str, **payload) -> dict:
-    """One telemetry row: the shared envelope every subsystem emits."""
-    return {"schema": SCHEMA, "kind": kind, **payload}
+# host spans and counters (the engine imports them from there)
+from repro.core.registry import (  # noqa: F401
+    SCHEMA, Registry, count, recent, record, since, snapshot_row, span,
+    totals)
 
 
 # --------------------------------------------------------------------------

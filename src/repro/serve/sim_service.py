@@ -122,7 +122,7 @@ class SimService:
                  max_batch: int = 32, max_wait_s: float = 0.05,
                  max_queue: int = 128, overflow: str = "serialize",
                  warmup: int = 8, measure: int = 24,
-                 clock=time.perf_counter, snapshot_every: int = 0):
+                 clock=time.perf_counter):
         if overflow not in ("serialize", "shed"):
             raise ValueError(f"overflow={overflow!r}: 'serialize' or 'shed'")
         if max_batch < 1 or max_queue < 1:
@@ -152,12 +152,11 @@ class SimService:
         self.n_serialized = 0     # overflow-forced inline flushes
         self.n_batches = 0
         self.recompiles = 0       # jit-cache growth across dispatches
+        self.queue_wait_s = 0.0   # enqueue -> flush start, summed over cells
+        self.n_queued = 0         # cells dispatched from the queue
         # bounded log-spaced latency histogram: percentiles (incl. p99.9)
-        # without retaining per-request records; plus optional periodic
-        # stats snapshots (telemetry.SCHEMA rows) every N completions
+        # without retaining per-request records
         self.lat_hist = telemetry.LatencyHistogram()
-        self.snapshot_every = snapshot_every
-        self.snapshots: list[dict] = []
 
     # ---- keying ----------------------------------------------------------
 
@@ -234,13 +233,22 @@ class SimService:
 
     def flush(self, now: float | None = None) -> int:
         """Dispatch every pending cell in ``max_batch``-sized batches through
-        the engine's jit-keyed chunked scan.  Returns cells dispatched."""
-        with self._lock:
+        the engine's jit-keyed chunked scan.  Returns cells dispatched.
+
+        Each cell's queue wait runs from its enqueue to ``now`` (default:
+        the clock when the flush starts)."""
+        with self._lock, telemetry.span("serve.flush"):
+            t_flush = self.clock() if now is None else now
             done = 0
             while self._pending:
                 keys = list(itertools.islice(iter(self._pending),
                                              self.max_batch))
                 batch = [self._pending.pop(k) for k in keys]
+                wait = sum(max(t_flush - c.t_enqueue, 0.0) for c in batch)
+                self.queue_wait_s += wait
+                self.n_queued += len(batch)
+                telemetry.count("serve.queue_wait_s", wait)
+                telemetry.count("serve.queued_cells", len(batch))
                 jc0 = eng.jit_cache_size()
                 times = eng.steady_state_time_batch(
                     [c.body for c in batch], [c.cfg for c in batch],
@@ -306,9 +314,6 @@ class SimService:
         self.completed.append(res)
         self._results[req.uid] = res
         self.lat_hist.add(res.latency_s)
-        if self.snapshot_every and not len(self.completed) % self.snapshot_every:
-            self.snapshots.append(telemetry.snapshot_row(
-                "serve.snapshot", t=t_done, **self.stats()))
         return res
 
     def result_for(self, uid: int) -> SimResult | None:
@@ -323,6 +328,7 @@ class SimService:
             "shed": self.n_shed, "serialized": self.n_serialized,
             "batches": self.n_batches, "recompiles": self.recompiles,
             "pending": self._waiting,
+            "queue_wait_s": self.queue_wait_s, "queued_cells": self.n_queued,
             "hit_fraction": self.n_hits / self.n_requests
             if self.n_requests else 0.0,
             "cache_entries": len(self.cache),

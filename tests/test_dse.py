@@ -340,6 +340,39 @@ def test_explore_repeat_is_bitwise_and_fully_cached(tmp_path):
     assert dse._frontier_fingerprint(r1) == dse._frontier_fingerprint(r2)
 
 
+def test_explore_phase_rows_counters_and_records():
+    """The key/dispatch/derive rows keep their names; one row per engine
+    span follows, and with dispatch's self time they make up its wall.
+    The study's counters are reported and kept, and every record carries
+    the batched engine's answer for its cell."""
+    from repro.core import telemetry
+
+    res = dse.explore(SP_TINY, apps=("blackscholes", "pathfinder"),
+                      cache=dse.ResultCache())
+    rows = res.stats["phases"]
+    assert [r["phase"] for r in rows[:3]] == ["key", "dispatch", "derive"]
+    spans = {r["phase"]: r for r in rows[3:]}
+    assert set(spans) == {"engine.build", "engine.stack", "engine.copy",
+                          "engine.launch", "engine.wait", "engine.readback"}
+    assert all(r["kind"] == "dse.phase" and r["parent"] == "dispatch"
+               and r["calls"] >= 1 for r in spans.values())
+    dispatch = rows[1]
+    assert dispatch["self_s"] + sum(r["wall_s"] for r in spans.values()) \
+        == pytest.approx(dispatch["wall_s"], rel=1e-9)
+    c = res.stats["counters"]
+    assert c["dse.cells"] == len(res.records) == 16
+    assert c["engine.lane_steps_real"] <= c["engine.lane_steps_scanned"]
+    assert telemetry.recent("dse.study")[-1]["counters"] == c
+    need = {}
+    for r in res.records:
+        body, key = dse.cell_key(r.app, r.cfg)
+        need.setdefault(key, (body, r.cfg))
+    want = dict(zip(need, eng.steady_state_time_batch(
+        [b for b, _ in need.values()], [c for _, c in need.values()])))
+    assert [r.steady_ns for r in res.records] == \
+        [want[dse.cell_key(r.app, r.cfg)[1]] for r in res.records]
+
+
 def test_explore_dedups_mvl_aliases_within_a_run():
     """streamcluster caps at max_vl=128: mvl=128 and mvl=256 induce the same
     clamped body AND the same timing parameters, so the cache dedups them to

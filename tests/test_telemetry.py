@@ -194,3 +194,146 @@ def test_dep_scalar_attribution_matches_table2():
         prof = eng.simulate(body.tile(8), CFG_REF, collect_stats=True)
         has = prof["stalls"]["dep_scalar"] > 0
         assert has == (app in scalar_comm), (app, prof["stalls"]["dep_scalar"])
+
+
+# --------------------------------------------------------------------------
+# host spans and counters (repro.core.registry, re-exported by telemetry)
+# --------------------------------------------------------------------------
+def _busy(seconds):
+    import time
+    t0 = time.perf_counter()
+    while time.perf_counter() - t0 < seconds:
+        pass
+
+
+def test_spans_nest_and_self_time_excludes_children():
+    reg = telemetry.Registry()
+    with reg.span("outer") as outer:
+        _busy(0.01)
+        with reg.span("inner") as inner:
+            _busy(0.02)
+        with reg.span("inner"):
+            pass
+    t = reg.totals()["spans"]
+    assert t["outer"]["calls"] == 1 and t["inner"]["calls"] == 2
+    assert t["outer"]["total_s"] == outer.wall_s
+    assert t["inner"]["total_s"] >= inner.wall_s >= 0.02
+    assert t["inner"]["self_s"] == t["inner"]["total_s"]   # no children
+    assert t["outer"]["self_s"] == pytest.approx(
+        t["outer"]["total_s"] - t["inner"]["total_s"], abs=1e-12)
+    assert 0.01 <= t["outer"]["self_s"] < t["outer"]["total_s"]
+
+
+def test_span_still_counts_when_its_block_raises():
+    reg = telemetry.Registry()
+    with pytest.raises(KeyError):
+        with reg.span("fails"):
+            raise KeyError("x")
+    with reg.span("after"):
+        pass
+    t = reg.totals()["spans"]
+    assert t["fails"]["calls"] == 1
+    assert t["after"]["self_s"] == t["after"]["total_s"]  # stack unwound
+
+
+def test_totals_since_and_recent_rows():
+    reg = telemetry.Registry(keep=3)
+    reg.count("a", 2)
+    before = reg.totals()
+    assert before["schema"] == telemetry.SCHEMA
+    assert before["kind"] == "telemetry.totals"
+    json.dumps(before)
+    reg.count("a", 3)
+    reg.count("b")
+    with reg.span("s"):
+        pass
+    d = reg.since(before)
+    assert d["counters"] == {"a": 3, "b": 1}
+    assert set(d["spans"]) == {"s"} and d["spans"]["s"]["calls"] == 1
+    assert reg.since(reg.totals()) == telemetry.snapshot_row(
+        "telemetry.totals", spans={}, counters={})
+    for i in range(5):
+        reg.record(telemetry.snapshot_row("x.row", i=i))
+    reg.record(telemetry.snapshot_row("y.row", i=9))
+    assert [r["i"] for r in reg.recent("x.row")] == [3, 4]   # bounded
+    assert [r["i"] for r in reg.recent("y.row")] == [9]
+
+
+def test_two_threads_keep_their_own_span_stacks():
+    """Spans on two threads at once: no count is lost, and a span on one
+    thread never becomes a child of a span on the other."""
+    import sys
+    import threading
+
+    reg = telemetry.Registry()
+    n = 200
+    barrier = threading.Barrier(2, timeout=30)
+
+    def work(name):
+        barrier.wait()
+        for _ in range(n):
+            with reg.span(name):
+                with reg.span(name + ".child"):
+                    reg.count("ticks")
+
+    old = sys.getswitchinterval()
+    sys.setswitchinterval(1e-6)
+    try:
+        threads = [threading.Thread(target=work, args=(k,))
+                   for k in ("t1", "t2")]
+        for t in threads:
+            t.start()
+        for t in threads:
+            t.join(timeout=60)
+    finally:
+        sys.setswitchinterval(old)
+    assert not any(t.is_alive() for t in threads)
+    tot = reg.totals()
+    assert tot["counters"]["ticks"] == 2 * n
+    for k in ("t1", "t2"):
+        s, c = tot["spans"][k], tot["spans"][k + ".child"]
+        assert s["calls"] == c["calls"] == n
+        assert s["self_s"] == pytest.approx(s["total_s"] - c["total_s"],
+                                            abs=1e-9)
+
+
+def test_module_registry_is_the_engines():
+    """telemetry's span/count/totals are the process registry the engine
+    and dse.explore write to."""
+    before = telemetry.totals()
+    body = tracegen.body_for("pathfinder", 64, CFG_REF)
+    eng.steady_state_time_batch([body] * 3, [CFG_REF])
+    d = telemetry.since(before)
+    assert {"engine.build", "engine.stack", "engine.copy", "engine.launch",
+            "engine.wait", "engine.readback"} <= set(d["spans"])
+    c = d["counters"]
+    length = eng.trace_len_bucket(
+        eng.trace_len_bucket(8 * len(body)) + 24 * len(body))
+    assert c["engine.launches"] == length // eng.CHUNK
+    assert c["engine.lane_steps_scanned"] == eng.batch_bucket(3) * length
+    assert c["engine.lane_steps_batch_pad"] == (eng.batch_bucket(3) - 3) \
+        * length
+    assert c["engine.lane_steps_real"] == 3 * 32 * len(body)
+
+
+def test_jit_cache_size_counts_what_the_jit_caches_hold():
+    """The public trace counter moves exactly as the jitted programs' own
+    cache sizes do, over new batch buckets, repeats and the sequential and
+    profiling programs."""
+    def cache_sizes():
+        n = (eng._simulate_jit._cache_size()
+             + eng._chunk_batch_jit._cache_size()
+             + eng._profile_jit._cache_size())
+        return n + sum(f._cache_size() for f in eng._SHARDED_JITS.values())
+
+    body = tracegen.body_for("jacobi-2d", 64, CFG_REF)
+    steps = [lambda b=b: eng.steady_state_time_batch([body] * b, [CFG_REF])
+             for b in (1, 3, 9, 20, 40, 9, 1)]
+    steps += [lambda: eng.simulate(body.tile(3), CFG_REF),
+              lambda: eng.simulate(body.tile(3), CFG_CORNER),
+              lambda: eng.simulate(body.tile(5), CFG_REF,
+                                   collect_stats=True)]
+    for step in steps:
+        n0, c0 = eng.jit_cache_size(), cache_sizes()
+        step()
+        assert eng.jit_cache_size() - n0 == cache_sizes() - c0

@@ -670,6 +670,12 @@ def _run_batch_group(traces: list[isa.Trace], cfgs: list[VectorEngineConfig],
     (repeating the first element), then scan chunk by chunk, carrying the
     engine state between dispatches.
 
+    Lanes that hold the same ``Trace`` object share one row of a table of
+    distinct traces (``engine.trace_rows`` counts its rows); each chunk's
+    per-lane ``[B, CHUNK]`` arrays are gathered from that table just before
+    their launch, so the host gathers chunk i+1 while the device scans
+    chunk i.  The scan program sees the same values and shapes either way.
+
     With ``collect_times`` the running per-lane "time" plus the lane/VMU
     busy accumulators after every chunk are also returned (each
     [n_chunks, B]) — ``steady_state_time_batch`` reads the warmup checkpoint
@@ -681,18 +687,27 @@ def _run_batch_group(traces: list[isa.Trace], cfgs: list[VectorEngineConfig],
     bb = _pow2_bucket(b)
     n_chunks = length // CHUNK
     with registry.span("engine.stack"):
-        stacked = isa.stack_traces(traces + [traces[0]] * (bb - b), length)
-        xs_np = [getattr(stacked, f) for f in _TRACE_FIELDS]
+        distinct = list({id(t): t for t in traces}.values())
+        row_of = {id(t): r for r, t in enumerate(distinct)}
+        lane_row = np.array([row_of[id(t)] for t in traces]
+                            + [0] * (bb - b), dtype=np.intp)
+        table = isa.stack_traces(distinct, length)
+        table_np = [getattr(table, f) for f in _TRACE_FIELDS]
         cols = list(zip(*(_cfg_params_np(c)
                           for c in (cfgs + [cfgs[0]] * (bb - b)))))
         params = tuple(jnp.asarray(np.stack(col)) for col in cols)
         carry = jax.tree.map(
             lambda a: jnp.zeros((bb,) + a.shape, a.dtype), _init_carry())
+    registry.count("engine.trace_rows", len(distinct))
     times, busy_l, busy_v = [], [], []
     for i in range(n_chunks):
+        # fresh arrays every chunk: an enqueued transfer may still read the
+        # previous ones while the device scans
+        with registry.span("engine.gather"):
+            xs_np = [np.take(a[:, i * CHUNK:(i + 1) * CHUNK], lane_row,
+                             axis=0) for a in table_np]
         with registry.span("engine.copy"):
-            xs = tuple(jnp.asarray(a[:, i * CHUNK:(i + 1) * CHUNK])
-                       for a in xs_np)
+            xs = tuple(jnp.asarray(a) for a in xs_np)
         with registry.span("engine.launch"):
             carry = _dispatch_chunk_batch(carry, xs, params, bb)
             if collect_times:
@@ -791,12 +806,18 @@ def steady_state_time_batch(bodies, cfgs, warmup: int = 8,
     if not bodies:
         return []
     traces, w_chunks = [], []
+    fused: dict[int, tuple] = {}     # id(body) -> (trace, warm-up chunks)
     with registry.span("engine.build"):
         for body in bodies:
-            warm = body.tile(warmup)
-            wlen = _len_bucket(len(warm))
-            traces.append(warm.pad_to(wlen).concat(body.tile(measure)))
-            w_chunks.append(wlen // CHUNK)
+            ent = fused.get(id(body))
+            if ent is None:
+                warm = body.tile(warmup)
+                wlen = _len_bucket(len(warm))
+                ent = fused[id(body)] = (
+                    warm.pad_to(wlen).concat(body.tile(measure)),
+                    wlen // CHUNK)
+            traces.append(ent[0])
+            w_chunks.append(ent[1])
     out: list = [0.0] * len(traces)
     for length, idxs in sorted(_group_by_length_bucket(traces).items()):
         rows, times, busy_l, busy_v = _run_batch_group(

@@ -157,3 +157,67 @@ def test_mixed_length_bucket_batch_matches_sequential():
         want = eng.simulate(tr, cfg)
         for k in want:
             _close(row[k], want[k])
+
+
+def _copy(tr):
+    """A content-equal Trace that is a distinct object."""
+    return isa.Trace(**{k: getattr(tr, k).copy()
+                        for k in isa.Trace.__dataclass_fields__})
+
+
+def _shared_mix():
+    """Five lanes (batch bucket 8, so three padding lanes) over two bodies:
+    one body object shared by three lanes, one of those a content-equal
+    copy, and a second body on two lanes."""
+    cfgs = [eng.VectorEngineConfig(mvl=64, lanes=l, ooo_issue=o)
+            for l, o in ((1, False), (4, False), (8, True), (2, False),
+                         (4, True))]
+    a = tracegen.body_for("jacobi-2d", 64, cfgs[0])
+    b = tracegen.body_for("pathfinder", 64, cfgs[0])
+    return [a, b, a, _copy(a), b], cfgs
+
+
+def test_lanes_sharing_a_trace_match_sequential_bitwise():
+    """Lanes that share a trace object read the same table row; every lane
+    still equals its own sequential run bitwise, padding lanes included."""
+    bodies, cfgs = _shared_mix()
+    tiled = {id(t): t.tile(2) for t in bodies}
+    traces = [tiled[id(t)] for t in bodies]
+    for tr, cfg, row in zip(traces, cfgs, eng.simulate_batch(traces, cfgs)):
+        assert row == eng.simulate(tr, cfg)
+    got = eng.steady_state_time_batch(bodies, cfgs, warmup=4, measure=8)
+    util = eng.steady_state_time_batch(bodies, cfgs, warmup=4, measure=8,
+                                       with_util=True)
+    for body, cfg, g, u in zip(bodies, cfgs, got, util):
+        assert g == eng.steady_state_time(body, cfg, warmup=4, measure=8)
+        assert u == eng.steady_state_time_batch(
+            [body], [cfg], warmup=4, measure=8, with_util=True)[0]
+        assert u["steady_ns"] == g
+
+
+def test_fused_trace_is_built_once_per_distinct_body(monkeypatch):
+    """``steady_state_time_batch`` tiles each distinct body object twice
+    (warm-up and measurement), not each lane."""
+    bodies, cfgs = _shared_mix()
+    calls = []
+    tile = isa.Trace.tile
+
+    def counting_tile(self, n):
+        calls.append(id(self))
+        return tile(self, n)
+
+    monkeypatch.setattr(isa.Trace, "tile", counting_tile)
+    eng.steady_state_time_batch(bodies, cfgs, warmup=4, measure=8)
+    assert len(calls) == 2 * len({id(b) for b in bodies})
+
+
+def test_shared_lanes_add_no_executable():
+    """A batch whose lanes share traces runs on the executable of its batch
+    bucket, the one an all-distinct batch of that bucket compiled."""
+    bodies, cfgs = _shared_mix()
+    eng.steady_state_time_batch([_copy(b) for b in bodies], cfgs,
+                                warmup=4, measure=8)
+    before = eng.jit_cache_size()
+    eng.steady_state_time_batch(bodies, cfgs, warmup=4, measure=8)
+    eng.simulate_batch([bodies[0].tile(2)] * 3, cfgs[:3])
+    assert eng.jit_cache_size() == before

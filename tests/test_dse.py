@@ -352,8 +352,9 @@ def test_explore_phase_rows_counters_and_records():
     rows = res.stats["phases"]
     assert [r["phase"] for r in rows[:3]] == ["key", "dispatch", "derive"]
     spans = {r["phase"]: r for r in rows[3:]}
-    assert set(spans) == {"engine.build", "engine.stack", "engine.copy",
-                          "engine.launch", "engine.wait", "engine.readback"}
+    assert set(spans) == {"engine.build", "engine.stack", "engine.gather",
+                          "engine.copy", "engine.launch", "engine.wait",
+                          "engine.readback"}
     assert all(r["kind"] == "dse.phase" and r["parent"] == "dispatch"
                and r["calls"] >= 1 for r in spans.values())
     dispatch = rows[1]

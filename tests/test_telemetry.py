@@ -337,3 +337,30 @@ def test_jit_cache_size_counts_what_the_jit_caches_hold():
         n0, c0 = eng.jit_cache_size(), cache_sizes()
         step()
         assert eng.jit_cache_size() - n0 == cache_sizes() - c0
+
+
+def test_trace_rows_count_distinct_objects_per_group():
+    """``engine.trace_rows`` adds, per batch group, the distinct trace
+    objects among its lanes: shared objects count once, content-equal
+    copies count apart, padding lanes add none."""
+    short = tracegen.body_for("pathfinder", 64, CFG_REF)
+    copy = short.tile(1)
+    long = tracegen.body_for("particlefilter", 64, CFG_REF).tile(2)
+    traces = [short, long, short, copy, long, short]
+    assert len({eng.trace_len_bucket(len(t)) for t in traces}) == 2
+    before = telemetry.totals()
+    eng.simulate_batch(traces, [CFG_REF])
+    assert telemetry.since(before)["counters"]["engine.trace_rows"] == 2 + 1
+
+
+def test_gather_span_runs_once_per_chunk():
+    body = tracegen.body_for("blackscholes", 64, CFG_REF)
+    before = telemetry.totals()
+    eng.steady_state_time_batch([body, body, body.tile(1)],
+                                [CFG_REF, CFG_CORNER, CFG_REF])
+    d = telemetry.since(before)
+    length = eng.trace_len_bucket(
+        eng.trace_len_bucket(8 * len(body)) + 24 * len(body))
+    assert d["spans"]["engine.gather"]["calls"] == length // eng.CHUNK \
+        == d["counters"]["engine.launches"]
+    assert d["counters"]["engine.trace_rows"] == 2

@@ -465,31 +465,39 @@ _chunk_batch_jit = jax.jit(jax.vmap(_chunk_core))
 _profile_jit = jax.jit(_profile_core)
 
 
-_SHARDED_JITS: dict[int, object] = {}
+# device count -> (sharded program, the sharding of its inputs)
+_SHARDED_JITS: dict[int, tuple] = {}
 
 
-def _sharded_chunk_program(devices):
-    """The batched chunk scan sharded over the config axis of ``devices``:
+def _sharded_chunk_program(mesh):
+    """The batched chunk scan sharded over the ``cfg`` axis of ``mesh``:
     an SPMD wrapper around the same vmapped ``_chunk_core``, so each device
     scans its slice of the batch and results are indistinguishable from the
     single-device path (the per-lane scan arithmetic is shared)."""
-    from jax.sharding import Mesh, PartitionSpec as P
+    from jax.sharding import PartitionSpec as P
 
-    mesh = Mesh(np.asarray(devices), ("cfg",))
     return jax.jit(jax.shard_map(jax.vmap(_chunk_core), mesh=mesh,
                                  in_specs=P("cfg"), out_specs=P("cfg")))
 
 
-def _sharded_chunk_jit(ndev: int):
-    """``_sharded_chunk_program`` over the first ``ndev`` local devices,
-    built lazily once per device count."""
-    f = _SHARDED_JITS.get(ndev)
-    if f is None:
+def _sharded(batch_bucket: int):
+    """The sharded program and where its inputs live (the batch axis split
+    over the ``cfg`` axis of a mesh of the local devices), built once per
+    device count.  ``None`` where the single-device program runs: on one
+    device, or for a power-of-two batch bucket the device count does not
+    divide."""
+    ndev = jax.local_device_count()
+    if ndev == 1 or batch_bucket % ndev:
+        return None
+    if ndev not in _SHARDED_JITS:
+        from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
+
         # local_devices, not devices: in a multi-process job the mesh must
         # hold only this process's addressable devices
-        f = _SHARDED_JITS[ndev] = _sharded_chunk_program(
-            jax.local_devices()[:ndev])
-    return f
+        mesh = Mesh(np.asarray(jax.local_devices()[:ndev]), ("cfg",))
+        _SHARDED_JITS[ndev] = (_sharded_chunk_program(mesh),
+                               NamedSharding(mesh, P("cfg")))
+    return _SHARDED_JITS[ndev]
 
 
 def _dispatch_chunk_batch(carry, xs, params, batch_bucket: int):
@@ -502,10 +510,9 @@ def _dispatch_chunk_batch(carry, xs, params, batch_bucket: int):
     boundary — so a many-config sweep scales with device count while the
     one-device fallback keeps every existing caller bitwise unchanged.
     """
-    ndev = jax.local_device_count()
-    if ndev > 1 and batch_bucket % ndev == 0:
-        return _sharded_chunk_jit(ndev)(carry, xs, params)
-    return _chunk_batch_jit(carry, xs, params)
+    sharded = _sharded(batch_bucket)
+    program = _chunk_batch_jit if sharded is None else sharded[0]
+    return program(carry, xs, params)
 
 # Batched traces are NOP-padded to multiples of CHUNK and scanned chunk by
 # chunk; the compilation key is (batch bucket, CHUNK) only.
@@ -686,6 +693,11 @@ def _run_batch_group(traces: list[isa.Trace], cfgs: list[VectorEngineConfig],
     b = len(traces)
     bb = _pow2_bucket(b)
     n_chunks = length // CHUNK
+    # the sharded program's inputs go from the host straight to their
+    # shards; the single-device program's (sharding None) to the default
+    # device
+    sharded = _sharded(bb)
+    sharding = None if sharded is None else sharded[1]
     with registry.span("engine.stack"):
         distinct = list({id(t): t for t in traces}.values())
         row_of = {id(t): r for r, t in enumerate(distinct)}
@@ -695,9 +707,11 @@ def _run_batch_group(traces: list[isa.Trace], cfgs: list[VectorEngineConfig],
         table_np = [getattr(table, f) for f in _TRACE_FIELDS]
         cols = list(zip(*(_cfg_params_np(c)
                           for c in (cfgs + [cfgs[0]] * (bb - b)))))
-        params = tuple(jnp.asarray(np.stack(col)) for col in cols)
+        params = tuple(jax.device_put(np.stack(col), sharding)
+                       for col in cols)
         carry = jax.tree.map(
-            lambda a: jnp.zeros((bb,) + a.shape, a.dtype), _init_carry())
+            lambda a: jnp.zeros((bb,) + a.shape, a.dtype, device=sharding),
+            _init_carry())
     registry.count("engine.trace_rows", len(distinct))
     times, busy_l, busy_v = [], [], []
     for i in range(n_chunks):
@@ -707,7 +721,7 @@ def _run_batch_group(traces: list[isa.Trace], cfgs: list[VectorEngineConfig],
             xs_np = [np.take(a[:, i * CHUNK:(i + 1) * CHUNK], lane_row,
                              axis=0) for a in table_np]
         with registry.span("engine.copy"):
-            xs = tuple(jnp.asarray(a) for a in xs_np)
+            xs = tuple(jax.device_put(a, sharding) for a in xs_np)
         with registry.span("engine.launch"):
             carry = _dispatch_chunk_batch(carry, xs, params, bb)
             if collect_times:
@@ -718,6 +732,12 @@ def _run_batch_group(traces: list[isa.Trace], cfgs: list[VectorEngineConfig],
     with registry.span("engine.wait"):
         jax.block_until_ready((carry, times, busy_l, busy_v))
     registry.count("engine.launches", n_chunks)
+    # counted on one device too, as 0: a fallback to one device inside a
+    # many-device study reads as a share below 100 %, not as a gap
+    registry.count("engine.sharded_launches",
+                   0 if sharding is None else n_chunks)
+    registry.count("engine.lane_steps_sharded",
+                   0 if sharding is None else bb * length)
     registry.count("engine.lane_steps_scanned", bb * length)
     registry.count("engine.lane_steps_batch_pad", (bb - b) * length)
     with registry.span("engine.readback"):

@@ -64,8 +64,7 @@ def vector_runtime_from_per_chunk(app_name: str, cfg: eng.VectorEngineConfig,
     # residual inconsistent whenever cfg.mvl > app.max_vl)
     counts = app.counts(mvl)
     # residual scalar work not amortized per chunk (s0-like constant part)
-    per_chunk_scalar = sum(
-        r for r in body.scalar_count)  # instrs already inside the body
+    per_chunk_scalar = sum(body.scalar_count)  # instrs already inside the body
     residual = max(counts.scalar_instrs - per_chunk_scalar * chunks, 0.0)
     # ns per residual instruction on the config's scalar core:
     # cycles / scalar clock / issue width (0.25 on the default 2 GHz

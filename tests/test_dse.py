@@ -15,6 +15,7 @@ import pytest
 from repro.core import dse
 from repro.core import engine as eng
 from repro.core import suite
+from repro.core import telemetry
 from repro.configs import vector_engine as vcfg
 
 
@@ -440,9 +441,10 @@ def test_suite_entry_points():
 # ------------------------------------------------------ sharded dispatch
 
 _SHARD_SCRIPT = r"""
+import json
 import jax
 assert jax.local_device_count() == 4, jax.local_device_count()
-from repro.core import engine as eng, tracegen
+from repro.core import dse, engine as eng, telemetry, tracegen
 cfg0 = eng.VectorEngineConfig(mvl=64, lanes=4)
 tr = tracegen.body_for("blackscholes", 64, cfg0).tile(2)
 cfgs = [eng.VectorEngineConfig(mvl=m, lanes=l)
@@ -454,14 +456,61 @@ for c, r in zip(cfgs, rows):
     for k in w:
         assert abs(r[k] - w[k]) <= 1e-5 * max(abs(w[k]), 1.0), (c.label(), k)
 print("SHARDED_OK")
+
+# placement: a study over both knobs no other study sets, its chunk inputs
+# and carry seen at the dispatch, against the single-device program
+space = dse.DesignSpace.of("wide", mvl=(8, 64), lanes=(1, 4),
+                           interconnect=("ring", "crossbar"),
+                           vrf_read_ports=(1, 2))
+apps = ("jacobi-2d", "pathfinder")
+seen = {"inputs": set(), "carry_out": set()}
+dispatch = eng._dispatch_chunk_batch
+
+
+def spy(carry, xs, params, bb):
+    for a in carry + xs + params:
+        seen["inputs"].add((repr(a.sharding), len(a.sharding.device_set)))
+    out = dispatch(carry, xs, params, bb)
+    for a in out:
+        seen["carry_out"].add((repr(a.sharding), len(a.sharding.device_set)))
+    return out
+
+
+def study():
+    before = telemetry.totals()
+    res = dse.explore(space, apps=apps, cache=dse.ResultCache())
+    rows = sorted((r.app, r.cfg.label(), r.cfg.interconnect,
+                   r.cfg.vrf_read_ports, r.steady_ns, r.runtime_ns,
+                   r.speedup) for r in res.records)
+    return rows, telemetry.since(before)["counters"]
+
+
+eng._dispatch_chunk_batch = spy
+sharded, counters = study()
+eng._dispatch_chunk_batch = dispatch
+n0 = eng.jit_cache_size()
+again, _ = study()
+repeat_compiles = eng.jit_cache_size() - n0
+# the single-device program on device 0, as on a one-chip host
+sharded_program = eng._sharded
+eng._sharded = lambda bb: None
+one, one_counters = study()
+eng._sharded = sharded_program
+print(json.dumps({
+    "sharded": sharded, "again": again, "one_device": one,
+    "counters": counters, "one_device_counters": one_counters,
+    "repeat_compiles": repeat_compiles,
+    "mesh": repr(eng._SHARDED_JITS[4][1]),
+    "inputs": sorted(seen["inputs"]),
+    "carry_out": sorted(seen["carry_out"]),
+}))
 """
 
 
-@pytest.mark.slow
-def test_sharded_dispatch_matches_sequential_subprocess():
-    """The DSE sharding contract: with >1 device the config axis runs
-    through shard_map and results equal the sequential path.  Forced host
-    devices need a fresh process (XLA flags are read at jax import)."""
+@pytest.fixture(scope="module")
+def four_devices():
+    """One subprocess on four forced host devices (XLA flags are read at
+    jax import) for every test of the sharded path below."""
     env = dict(os.environ,
                XLA_FLAGS=(os.environ.get("XLA_FLAGS", "")
                           + " --xla_force_host_platform_device_count=4"),
@@ -471,15 +520,69 @@ def test_sharded_dispatch_matches_sequential_subprocess():
                           env=env, capture_output=True, text=True,
                           timeout=540)
     assert proc.returncode == 0, proc.stderr[-2000:]
-    assert "SHARDED_OK" in proc.stdout
+    return proc.stdout
+
+
+@pytest.fixture(scope="module")
+def placement(four_devices):
+    return json.loads(four_devices.strip().splitlines()[-1])
+
+
+@pytest.mark.slow
+def test_sharded_dispatch_matches_sequential_subprocess(four_devices):
+    """The DSE sharding contract: with >1 device the config axis runs
+    through shard_map and results equal the sequential path."""
+    assert "SHARDED_OK" in four_devices
+
+
+def test_sharded_study_answers_bitwise_as_device_0(placement):
+    """Both knobs no earlier study set (crossbar, two VRF read ports) are
+    in the space, and every answer equals the single-device program's."""
+    rows = placement["sharded"]
+    assert len(rows) == 2 * 16
+    assert {(r[2], r[3]) for r in rows} == {
+        ("ring", 1), ("ring", 2), ("crossbar", 1), ("crossbar", 2)}
+    assert placement["sharded"] == placement["one_device"]
+    assert placement["again"] == placement["sharded"]
+
+
+def test_chunk_inputs_and_carry_span_four_devices(placement):
+    mesh = placement["mesh"]
+    assert "'cfg': 4" in mesh and "PartitionSpec('cfg',)" in mesh
+    for key in ("inputs", "carry_out"):
+        assert placement[key] == [[mesh, 4]], key
+
+
+def test_every_launch_of_the_study_is_sharded(placement):
+    c = placement["counters"]
+    assert c["engine.launches"] > 0
+    assert c["engine.sharded_launches"] == c["engine.launches"]
+    assert c["engine.lane_steps_sharded"] == c["engine.lane_steps_scanned"]
+    # a repeat of the study compiles nothing: the carry the sharded
+    # program returns has the layout its inputs were given
+    assert placement["repeat_compiles"] == 0
+
+
+def test_single_device_program_counts_no_sharded_launch(placement):
+    c = placement["one_device_counters"]
+    assert c["engine.launches"] == placement["counters"]["engine.launches"]
+    assert "engine.sharded_launches" not in c
+    assert "engine.lane_steps_sharded" not in c
 
 
 def test_single_device_fallback_never_builds_sharded_jit():
     """On one device (the default CI environment) every dispatch takes the
-    chunked single-device path — the fallback half of the contract."""
+    chunked single-device path — the fallback half of the contract — and
+    the sharded-launch counters count 0, not nothing."""
     import jax
     if jax.local_device_count() != 1:
         pytest.skip("multi-device environment")
-    dse.explore(dse.DesignSpace.of("t1", mvl=(16,), lanes=(2, 4)),
-                apps=("pathfinder",))
+    before = telemetry.totals()["counters"]
+    dse.explore(dse.DesignSpace.of("t1", mvl=(16,), lanes=(2, 4),
+                                   interconnect=("ring", "crossbar")),
+                apps=("pathfinder",), cache=dse.ResultCache())
+    after = telemetry.totals()["counters"]
+    assert after["engine.launches"] > before.get("engine.launches", 0)
+    for name in ("engine.sharded_launches", "engine.lane_steps_sharded"):
+        assert after[name] == 0, name
     assert eng._SHARDED_JITS == {}

@@ -324,7 +324,7 @@ def test_jit_cache_size_counts_what_the_jit_caches_hold():
         n = (eng._simulate_jit._cache_size()
              + eng._chunk_batch_jit._cache_size()
              + eng._profile_jit._cache_size())
-        return n + sum(f._cache_size() for f in eng._SHARDED_JITS.values())
+        return n + sum(f._cache_size() for f, _ in eng._SHARDED_JITS.values())
 
     body = tracegen.body_for("jacobi-2d", 64, CFG_REF)
     steps = [lambda b=b: eng.steady_state_time_batch([body] * b, [CFG_REF])

@@ -78,8 +78,9 @@ def test_chunk_scan_compiles_on_one_chip(one_chip):
 def test_sharded_chunk_scan_compiles_on_four_chips(topo):
     devices = topo.devices[:4]
     assert len(devices) == 4
-    cfg_axis = NamedSharding(Mesh(np.asarray(devices), ("cfg",)), P("cfg"))
-    compiled = eng._sharded_chunk_program(devices).lower(
+    mesh = Mesh(np.asarray(devices), ("cfg",))
+    cfg_axis = NamedSharding(mesh, P("cfg"))
+    compiled = eng._sharded_chunk_program(mesh).lower(
         *_chunk_args(cfg_axis)).compile()
     hlo = compiled.as_text()
     # the config axis is embarrassingly parallel: nothing crosses a shard

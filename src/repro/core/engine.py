@@ -33,10 +33,12 @@ from __future__ import annotations
 import functools
 import hashlib
 from dataclasses import dataclass, fields
+from typing import Callable, NamedTuple
 
 import jax
 import jax.numpy as jnp
 import numpy as np
+from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
 
 from repro.core import isa
 from repro.core import memory
@@ -427,7 +429,7 @@ def _init_carry_stats():
                             jnp.zeros(4, jnp.float32))
 
 
-# Each scan program counts one ``engine.traces`` in its Python body, which
+# Each engine program counts one ``engine.traces`` in its Python body, which
 # runs once per jit cache miss (``jit_cache_size``).
 def _scan_core(xs, params):
     """One trace x one config, full-length scan -> timing dict."""
@@ -460,13 +462,36 @@ def _chunk_core(carry, xs, params):
     return carry
 
 
+def _gather_rows(table, rows):
+    """Each lane's row of one chunk of the table of distinct traces: the
+    ``[B, CHUNK]`` fields the scan program reads, gathered on the device.
+    A program of its own, so the scan executables and their key stay as
+    they are and the scan sees the same values in the same shapes."""
+    registry.count("engine.traces")
+    return tuple(jnp.take(t, rows, axis=0, mode="clip") for t in table)
+
+
 _simulate_jit = jax.jit(_scan_core)
 _chunk_batch_jit = jax.jit(jax.vmap(_chunk_core))
 _profile_jit = jax.jit(_profile_core)
+_gather_jit = jax.jit(_gather_rows)
 
 
-# device count -> (sharded program, the sharding of its inputs)
-_SHARDED_JITS: dict[int, tuple] = {}
+class _Placement(NamedTuple):
+    """The chunk programs of a batch bucket and where their inputs live.
+    ``lanes`` places what has a lane axis (rows, parameters, carry; the
+    gather's output), ``table`` a chunk of the table of distinct traces;
+    ``None`` is the default device, uncommitted."""
+    scan: Callable
+    gather: Callable
+    lanes: object
+    table: object
+
+
+_ONE_DEVICE = _Placement(_chunk_batch_jit, _gather_jit, None, None)
+
+# device count -> the sharded placement
+_SHARDED_JITS: dict[int, _Placement] = {}
 
 
 def _sharded_chunk_program(mesh):
@@ -474,29 +499,34 @@ def _sharded_chunk_program(mesh):
     an SPMD wrapper around the same vmapped ``_chunk_core``, so each device
     scans its slice of the batch and results are indistinguishable from the
     single-device path (the per-lane scan arithmetic is shared)."""
-    from jax.sharding import PartitionSpec as P
-
     return jax.jit(jax.shard_map(jax.vmap(_chunk_core), mesh=mesh,
                                  in_specs=P("cfg"), out_specs=P("cfg")))
 
 
-def _sharded(batch_bucket: int):
-    """The sharded program and where its inputs live (the batch axis split
-    over the ``cfg`` axis of a mesh of the local devices), built once per
-    device count.  ``None`` where the single-device program runs: on one
-    device, or for a power-of-two batch bucket the device count does not
-    divide."""
+def _sharded_gather_program(mesh):
+    """The chunk gather over the ``cfg`` axis of ``mesh``: the table chunk
+    replicated, the lane rows split, so each device gathers its own slice
+    of the batch with no collective."""
+    return jax.jit(jax.shard_map(_gather_rows, mesh=mesh,
+                                 in_specs=(P(), P("cfg")),
+                                 out_specs=P("cfg")))
+
+
+def _placement(batch_bucket: int) -> _Placement:
+    """The programs of a batch bucket and where their inputs live: sharded
+    over the ``cfg`` axis of a mesh of the local devices (built once per
+    device count), or on the default device where there is one device or
+    the device count does not divide the power-of-two batch bucket."""
     ndev = jax.local_device_count()
     if ndev == 1 or batch_bucket % ndev:
-        return None
+        return _ONE_DEVICE
     if ndev not in _SHARDED_JITS:
-        from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
-
         # local_devices, not devices: in a multi-process job the mesh must
         # hold only this process's addressable devices
         mesh = Mesh(np.asarray(jax.local_devices()[:ndev]), ("cfg",))
-        _SHARDED_JITS[ndev] = (_sharded_chunk_program(mesh),
-                               NamedSharding(mesh, P("cfg")))
+        _SHARDED_JITS[ndev] = _Placement(
+            _sharded_chunk_program(mesh), _sharded_gather_program(mesh),
+            NamedSharding(mesh, P("cfg")), NamedSharding(mesh, P()))
     return _SHARDED_JITS[ndev]
 
 
@@ -510,9 +540,7 @@ def _dispatch_chunk_batch(carry, xs, params, batch_bucket: int):
     boundary — so a many-config sweep scales with device count while the
     one-device fallback keeps every existing caller bitwise unchanged.
     """
-    sharded = _sharded(batch_bucket)
-    program = _chunk_batch_jit if sharded is None else sharded[0]
-    return program(carry, xs, params)
+    return _placement(batch_bucket).scan(carry, xs, params)
 
 # Batched traces are NOP-padded to multiples of CHUNK and scanned chunk by
 # chunk; the compilation key is (batch bucket, CHUNK) only.
@@ -644,7 +672,8 @@ def _len_bucket(n: int) -> int:
 def batch_bucket(n: int) -> int:
     """The power-of-two batch bucket a batch of ``n`` (trace, config) pairs
     pads to.  Together with ``CHUNK`` this is the *only* jit-compilation key
-    of the batched path — the contract the serve layer
+    of the batched scan, and with the row bucket that of its chunk gather
+    (``warm_gather``) — the contract the serve layer
     (``repro.serve.sim_service``) builds on: prewarm one executable per
     bucket up to the service's ``max_batch`` and steady-state serving never
     recompiles."""
@@ -661,14 +690,36 @@ def trace_len_bucket(n: int) -> int:
 
 def jit_cache_size() -> int:
     """Number of engine executables compiled so far (sequential, batched,
-    profiling and sharded): the ``engine.traces`` counter, which each scan
-    program's Python body adds to once per jit cache miss.  An
-    ahead-of-time ``.lower()`` of one of them counts as well.
+    profiling, chunk gather, and their sharded forms): the ``engine.traces``
+    counter, which each program's Python body adds to once per jit cache
+    miss.  An ahead-of-time ``.lower()`` of one of them counts as well.
 
-    The batched path's compilation key is (batch bucket, CHUNK) only: flags
-    are traced, lengths are chunked, batch sizes are padded to powers of two.
+    The batched scan's compilation key is (batch bucket, CHUNK) only: flags
+    are traced, lengths are chunked, batch sizes are padded to powers of
+    two.  The chunk gather's is (batch bucket, row bucket, CHUNK).
     """
     return int(registry.totals()["counters"].get("engine.traces", 0))
+
+
+def _put_chunk(table_np, i: int, sharding):
+    """Chunk ``i`` of the table of distinct traces, placed for the gather."""
+    return tuple(jax.device_put(a[:, i * CHUNK:(i + 1) * CHUNK], sharding)
+                 for a in table_np)
+
+
+def warm_gather(batch_bucket: int) -> None:
+    """Compile the chunk gather of ``batch_bucket`` lanes at every row
+    bucket up to it (8, 16, ..., ``batch_bucket``), placed as a batch
+    group places it, so no later group of that bucket compiles one."""
+    on = _placement(batch_bucket)
+    rows = jax.device_put(np.zeros(batch_bucket, np.int32), on.lanes)
+    r = 8
+    while r <= batch_bucket:
+        nops = isa.stack_traces([isa.nop_trace(CHUNK)] * r)
+        chunk = _put_chunk([getattr(nops, f) for f in _TRACE_FIELDS], 0,
+                           on.table)
+        jax.block_until_ready(on.gather(chunk, rows))
+        r *= 2
 
 
 def _run_batch_group(traces: list[isa.Trace], cfgs: list[VectorEngineConfig],
@@ -678,10 +729,13 @@ def _run_batch_group(traces: list[isa.Trace], cfgs: list[VectorEngineConfig],
     engine state between dispatches.
 
     Lanes that hold the same ``Trace`` object share one row of a table of
-    distinct traces (``engine.trace_rows`` counts its rows); each chunk's
-    per-lane ``[B, CHUNK]`` arrays are gathered from that table just before
-    their launch, so the host gathers chunk i+1 while the device scans
-    chunk i.  The scan program sees the same values and shapes either way.
+    distinct traces (``engine.trace_rows`` counts its rows), padded with
+    NOP rows that no lane reads to a power-of-two row bucket.  The lanes'
+    ``[B]`` row indices go to the devices once per group and each chunk's
+    ``[rows, CHUNK]`` slice of the table once per chunk
+    (``engine.trace_bytes_put`` counts both); a small program gathers the
+    chunk's per-lane ``[B, CHUNK]`` fields there before the scan program
+    runs.  The scan program sees the same values and shapes either way.
 
     With ``collect_times`` the running per-lane "time" plus the lane/VMU
     busy accumulators after every chunk are also returned (each
@@ -693,35 +747,39 @@ def _run_batch_group(traces: list[isa.Trace], cfgs: list[VectorEngineConfig],
     b = len(traces)
     bb = _pow2_bucket(b)
     n_chunks = length // CHUNK
-    # the sharded program's inputs go from the host straight to their
-    # shards; the single-device program's (sharding None) to the default
+    # the sharded programs' inputs go from the host straight to their
+    # devices; the single-device programs' (sharding None) to the default
     # device
-    sharded = _sharded(bb)
-    sharding = None if sharded is None else sharded[1]
+    on = _placement(bb)
     with registry.span("engine.stack"):
         distinct = list({id(t): t for t in traces}.values())
         row_of = {id(t): r for r, t in enumerate(distinct)}
         lane_row = np.array([row_of[id(t)] for t in traces]
-                            + [0] * (bb - b), dtype=np.intp)
-        table = isa.stack_traces(distinct, length)
+                            + [0] * (bb - b), dtype=np.int32)
+        pad = _pow2_bucket(len(distinct)) - len(distinct)
+        table = isa.stack_traces(distinct + [isa.nop_trace(length)] * pad,
+                                 length)
         table_np = [getattr(table, f) for f in _TRACE_FIELDS]
         cols = list(zip(*(_cfg_params_np(c)
                           for c in (cfgs + [cfgs[0]] * (bb - b)))))
-        params = tuple(jax.device_put(np.stack(col), sharding)
+        params = tuple(jax.device_put(np.stack(col), on.lanes)
                        for col in cols)
         carry = jax.tree.map(
-            lambda a: jnp.zeros((bb,) + a.shape, a.dtype, device=sharding),
+            lambda a: jnp.zeros((bb,) + a.shape, a.dtype, device=on.lanes),
             _init_carry())
+    with registry.span("engine.copy"):
+        rows = jax.device_put(lane_row, on.lanes)
     registry.count("engine.trace_rows", len(distinct))
+    # a replicated chunk counts once per device that holds it
+    copies = 1 if on.table is None else len(on.table.device_set)
+    registry.count("engine.trace_bytes_put", lane_row.nbytes
+                   + copies * sum(a.nbytes for a in table_np))
     times, busy_l, busy_v = [], [], []
     for i in range(n_chunks):
-        # fresh arrays every chunk: an enqueued transfer may still read the
-        # previous ones while the device scans
-        with registry.span("engine.gather"):
-            xs_np = [np.take(a[:, i * CHUNK:(i + 1) * CHUNK], lane_row,
-                             axis=0) for a in table_np]
         with registry.span("engine.copy"):
-            xs = tuple(jax.device_put(a, sharding) for a in xs_np)
+            chunk = _put_chunk(table_np, i, on.table)
+        with registry.span("engine.gather"):
+            xs = on.gather(chunk, rows)
         with registry.span("engine.launch"):
             carry = _dispatch_chunk_batch(carry, xs, params, bb)
             if collect_times:
@@ -735,9 +793,9 @@ def _run_batch_group(traces: list[isa.Trace], cfgs: list[VectorEngineConfig],
     # counted on one device too, as 0: a fallback to one device inside a
     # many-device study reads as a share below 100 %, not as a gap
     registry.count("engine.sharded_launches",
-                   0 if sharding is None else n_chunks)
+                   0 if on.lanes is None else n_chunks)
     registry.count("engine.lane_steps_sharded",
-                   0 if sharding is None else bb * length)
+                   0 if on.lanes is None else bb * length)
     registry.count("engine.lane_steps_scanned", bb * length)
     registry.count("engine.lane_steps_batch_pad", (bb - b) * length)
     with registry.span("engine.readback"):

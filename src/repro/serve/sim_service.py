@@ -18,8 +18,9 @@ through three tiers:
   ``engine.steady_state_time_batch`` — the same ``(batch bucket, CHUNK)``
   jit-keyed chunked scan (sharded over devices when >1) every sweep uses —
   so a service answer is bitwise the sweep answer.  :meth:`SimService.prewarm`
-  compiles one executable per power-of-two batch bucket up front, after
-  which steady-state serving never recompiles.
+  compiles one scan executable per power-of-two batch bucket, and the chunk
+  gather at every row bucket of each, up front, after which steady-state
+  serving never recompiles.
 
 Robustness contract: the queue is bounded (``max_queue`` waiting requests);
 on overflow the service degrades gracefully — ``overflow="serialize"``
@@ -277,8 +278,9 @@ class SimService:
 
     def prewarm(self) -> int:
         """Compile the batched scan at every power-of-two batch bucket up to
-        ``max_batch`` (the only jit key of the batched path), so steady-state
-        serving never recompiles.  Returns the number of buckets warmed."""
+        ``max_batch`` (the only jit key of the batched scan), and its chunk
+        gather at every row bucket up to each, so steady-state serving never
+        recompiles.  Returns the number of batch buckets warmed."""
         with self._lock:
             cfg = eng.VectorEngineConfig(mvl=8, lanes=1)
             body = tracegen.body_for("blackscholes",
@@ -292,6 +294,7 @@ class SimService:
                 eng.steady_state_time_batch([body] * b, [cfg] * b,
                                             warmup=self.warmup,
                                             measure=self.measure)
+                eng.warm_gather(b)
             return len(buckets)
 
     # ---- completion ------------------------------------------------------

@@ -5,6 +5,7 @@ is timing-neutral), so agreement is expected to be bitwise; the asserts allow
 1e-5 relative slack for XLA fusion differences, far inside the 1e-3 the
 reproduction tolerates.
 """
+import jax.numpy as jnp
 import numpy as np
 import pytest
 
@@ -221,3 +222,59 @@ def test_shared_lanes_add_no_executable():
     eng.steady_state_time_batch(bodies, cfgs, warmup=4, measure=8)
     eng.simulate_batch([bodies[0].tile(2)] * 3, cfgs[:3])
     assert eng.jit_cache_size() == before
+
+
+def _host_gather(chunk, rows):
+    """Each lane's row of a table chunk taken on the host and put on the
+    default device: the per-lane fields the scan read before the gather
+    moved onto the device."""
+    rows = np.asarray(rows)
+    return tuple(jnp.asarray(np.take(np.asarray(a), rows, axis=0))
+                 for a in chunk)
+
+
+def _distinct_mix(n):
+    """``n`` lanes, each its own body object: copies of two bodies, taken
+    in turn."""
+    cfgs = [eng.VectorEngineConfig(mvl=64, lanes=(1, 2, 4, 8)[i % 4],
+                                   ooo_issue=bool(i % 3))
+            for i in range(n)]
+    bodies = [_copy(tracegen.body_for(("jacobi-2d", "pathfinder")[i % 2],
+                                      64, cfgs[0])) for i in range(n)]
+    return bodies, cfgs
+
+
+def _row_bucket_mix():
+    """Twenty lanes over twelve distinct bodies: a row bucket (16) between
+    the least (8) and the batch bucket (32)."""
+    bodies, cfgs = _distinct_mix(12)
+    return bodies + bodies[:8], cfgs + cfgs[:8]
+
+
+# mix -> (lanes and configs, (row bucket, batch bucket))
+MIXES = {"shared": (_shared_mix, (8, 8)),
+         "distinct": (lambda: _distinct_mix(8), (8, 8)),
+         "row_bucket": (_row_bucket_mix, (16, 32))}
+
+
+@pytest.mark.parametrize("mix", sorted(MIXES))
+def test_device_gather_matches_a_host_gather_bitwise(mix, monkeypatch):
+    """Each chunk's lane fields gathered on the device from the table of
+    distinct traces give the answers of the same fields gathered on the
+    host, bitwise, in both batched entry points."""
+    make, buckets = MIXES[mix]
+    bodies, cfgs = make()
+    rows = len({id(b) for b in bodies})
+    assert (eng._pow2_bucket(rows), eng.batch_bucket(len(bodies))) == buckets
+    tiled = {id(t): t.tile(2) for t in bodies}
+    traces = [tiled[id(t)] for t in bodies]
+
+    def both():
+        return (eng.simulate_batch(traces, cfgs),
+                eng.steady_state_time_batch(bodies, cfgs, warmup=4,
+                                            measure=8, with_util=True))
+
+    on_device = both()
+    monkeypatch.setattr(eng, "_ONE_DEVICE",
+                        eng._ONE_DEVICE._replace(gather=_host_gather))
+    assert both() == on_device

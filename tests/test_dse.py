@@ -443,6 +443,8 @@ def test_suite_entry_points():
 _SHARD_SCRIPT = r"""
 import json
 import jax
+import jax.numpy as jnp
+import numpy as np
 assert jax.local_device_count() == 4, jax.local_device_count()
 from repro.core import dse, engine as eng, telemetry, tracegen
 cfg0 = eng.VectorEngineConfig(mvl=64, lanes=4)
@@ -463,17 +465,37 @@ space = dse.DesignSpace.of("wide", mvl=(8, 64), lanes=(1, 4),
                            interconnect=("ring", "crossbar"),
                            vrf_read_ports=(1, 2))
 apps = ("jacobi-2d", "pathfinder")
-seen = {"inputs": set(), "carry_out": set()}
+seen = {k: set() for k in ("inputs", "carry_out", "gather_table",
+                           "gather_rows", "gather_out")}
 dispatch = eng._dispatch_chunk_batch
+placed = eng._SHARDED_JITS[4]
+
+
+def where(key, arrays):
+    for a in arrays:
+        seen[key].add((repr(a.sharding), len(a.sharding.device_set)))
 
 
 def spy(carry, xs, params, bb):
-    for a in carry + xs + params:
-        seen["inputs"].add((repr(a.sharding), len(a.sharding.device_set)))
+    where("inputs", carry + xs + params)
     out = dispatch(carry, xs, params, bb)
-    for a in out:
-        seen["carry_out"].add((repr(a.sharding), len(a.sharding.device_set)))
+    where("carry_out", out)
     return out
+
+
+def gather_spy(chunk, rows):
+    where("gather_table", chunk)
+    where("gather_rows", [rows])
+    out = placed.gather(chunk, rows)
+    where("gather_out", out)
+    return out
+
+
+# each lane's row taken on the host and put on the default device
+def host_gather(chunk, rows):
+    rows = np.asarray(rows)
+    return tuple(jnp.asarray(np.take(np.asarray(a), rows, axis=0))
+                 for a in chunk)
 
 
 def study():
@@ -486,23 +508,28 @@ def study():
 
 
 eng._dispatch_chunk_batch = spy
+eng._SHARDED_JITS[4] = placed._replace(gather=gather_spy)
 sharded, counters = study()
+eng._SHARDED_JITS[4] = placed
 eng._dispatch_chunk_batch = dispatch
 n0 = eng.jit_cache_size()
 again, _ = study()
 repeat_compiles = eng.jit_cache_size() - n0
-# the single-device program on device 0, as on a one-chip host
-sharded_program = eng._sharded
-eng._sharded = lambda bb: None
+# the single-device programs on device 0, as on a one-chip host, and the
+# same with each chunk gathered on the host
+placement = eng._placement
+eng._placement = lambda bb: eng._ONE_DEVICE
 one, one_counters = study()
-eng._sharded = sharded_program
+eng._placement = lambda bb: eng._ONE_DEVICE._replace(gather=host_gather)
+host, _ = study()
+eng._placement = placement
 print(json.dumps({
     "sharded": sharded, "again": again, "one_device": one,
+    "host_gathered": host,
     "counters": counters, "one_device_counters": one_counters,
     "repeat_compiles": repeat_compiles,
-    "mesh": repr(eng._SHARDED_JITS[4][1]),
-    "inputs": sorted(seen["inputs"]),
-    "carry_out": sorted(seen["carry_out"]),
+    "mesh": repr(placed.lanes), "replicated": repr(placed.table),
+    **{k: sorted(v) for k, v in seen.items()},
 }))
 """
 
@@ -544,13 +571,19 @@ def test_sharded_study_answers_bitwise_as_device_0(placement):
         ("ring", 1), ("ring", 2), ("crossbar", 1), ("crossbar", 2)}
     assert placement["sharded"] == placement["one_device"]
     assert placement["again"] == placement["sharded"]
+    # the chunks gathered on the devices answer as a host gather does
+    assert placement["sharded"] == placement["host_gathered"]
 
 
 def test_chunk_inputs_and_carry_span_four_devices(placement):
     mesh = placement["mesh"]
     assert "'cfg': 4" in mesh and "PartitionSpec('cfg',)" in mesh
-    for key in ("inputs", "carry_out"):
+    for key in ("inputs", "carry_out", "gather_rows", "gather_out"):
         assert placement[key] == [[mesh, 4]], key
+    # the table chunk goes whole to every device, none resharded from one
+    replicated = placement["replicated"]
+    assert "'cfg': 4" in replicated and "PartitionSpec()" in replicated
+    assert placement["gather_table"] == [[replicated, 4]]
 
 
 def test_every_launch_of_the_study_is_sharded(placement):

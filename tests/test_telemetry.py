@@ -318,17 +318,23 @@ def test_module_registry_is_the_engines():
 
 def test_jit_cache_size_counts_what_the_jit_caches_hold():
     """The public trace counter moves exactly as the jitted programs' own
-    cache sizes do, over new batch buckets, repeats and the sequential and
-    profiling programs."""
+    cache sizes do, over new batch and row buckets, repeats and the
+    sequential, profiling and gather programs."""
     def cache_sizes():
         n = (eng._simulate_jit._cache_size()
              + eng._chunk_batch_jit._cache_size()
-             + eng._profile_jit._cache_size())
-        return n + sum(f._cache_size() for f, _ in eng._SHARDED_JITS.values())
+             + eng._profile_jit._cache_size()
+             + eng._gather_jit._cache_size())
+        return n + sum(p.scan._cache_size() + p.gather._cache_size()
+                       for p in eng._SHARDED_JITS.values())
 
     body = tracegen.body_for("jacobi-2d", 64, CFG_REF)
     steps = [lambda b=b: eng.steady_state_time_batch([body] * b, [CFG_REF])
              for b in (1, 3, 9, 20, 40, 9, 1)]
+    # distinct body objects: gathers at row buckets above the first
+    steps += [lambda n=n: eng.steady_state_time_batch(
+        [body.tile(1) for _ in range(n)], [CFG_REF]) for n in (20, 9, 20)]
+    steps += [lambda: eng.warm_gather(16)]
     steps += [lambda: eng.simulate(body.tile(3), CFG_REF),
               lambda: eng.simulate(body.tile(3), CFG_CORNER),
               lambda: eng.simulate(body.tile(5), CFG_REF,

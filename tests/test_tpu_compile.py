@@ -1,9 +1,10 @@
 """Compile the device programs for a described TPU v5e, no chip attached.
 
 The TPU compiler is installed with JAX, so what it would refuse on the chip
-it refuses here: the engine's batched chunk scan at a real batch, its
-``shard_map`` version over a 2x2 host, and the Pallas kernels at the
-``benchmarks/run.py --kernels`` shapes.  Nothing runs, so these tests say
+it refuses here: the engine's batched chunk scan at a real batch and its
+chunk gather at a study's largest batch, their ``shard_map`` versions over a
+2x2 host, and the Pallas kernels at the ``benchmarks/run.py --kernels``
+shapes.  Nothing runs, so these tests say
 nothing about results or times; ``chip_smoke.py`` runs the programs.
 
 The topology is described inside a module fixture (never at import): only
@@ -26,6 +27,8 @@ from repro.core import tracegen
 from repro.kernels import ops
 
 BATCH = 64
+# a study's largest batch bucket on one chip and its table's row bucket
+GATHER_LANES, GATHER_ROWS = 8192, 64
 COLLECTIVES = ("all-reduce", "all-gather", "all-to-all", "collective-permute",
                "reduce-scatter")
 
@@ -53,6 +56,13 @@ def one_chip(topo):
     return SingleDeviceSharding(topo.devices[0])
 
 
+@pytest.fixture(scope="module")
+def four_chips(topo):
+    devices = topo.devices[:4]
+    assert len(devices) == 4
+    return Mesh(np.asarray(devices), ("cfg",))
+
+
 def _sds(shape, dtype, sharding):
     return jax.ShapeDtypeStruct(shape, jnp.dtype(dtype), sharding=sharding)
 
@@ -75,15 +85,37 @@ def test_chunk_scan_compiles_on_one_chip(one_chip):
     assert compiled.memory_analysis() is not None
 
 
-def test_sharded_chunk_scan_compiles_on_four_chips(topo):
-    devices = topo.devices[:4]
-    assert len(devices) == 4
-    mesh = Mesh(np.asarray(devices), ("cfg",))
-    cfg_axis = NamedSharding(mesh, P("cfg"))
-    compiled = eng._sharded_chunk_program(mesh).lower(
+def test_sharded_chunk_scan_compiles_on_four_chips(four_chips):
+    cfg_axis = NamedSharding(four_chips, P("cfg"))
+    compiled = eng._sharded_chunk_program(four_chips).lower(
         *_chunk_args(cfg_axis)).compile()
     hlo = compiled.as_text()
     # the config axis is embarrassingly parallel: nothing crosses a shard
+    assert not [c for c in COLLECTIVES if c in hlo]
+
+
+def _gather_args(table, lanes, n_lanes):
+    """(table chunk, lane rows) shapes of one chunk gather."""
+    body = tracegen.body_for("blackscholes", 64, eng.VectorEngineConfig())
+    chunk = tuple(_sds((GATHER_ROWS, eng.CHUNK), getattr(body, f).dtype,
+                       table) for f in eng._TRACE_FIELDS)
+    return chunk, _sds((n_lanes,), jnp.int32, lanes)
+
+
+def test_chunk_gather_compiles_on_one_chip(one_chip):
+    compiled = eng._gather_jit.lower(
+        *_gather_args(one_chip, one_chip, GATHER_LANES)).compile()
+    assert compiled.memory_analysis() is not None
+
+
+def test_sharded_chunk_gather_compiles_on_four_chips(four_chips):
+    """Each chip gathers its quarter of the lanes from its own copy of the
+    table chunk: no collective."""
+    compiled = eng._sharded_gather_program(four_chips).lower(
+        *_gather_args(NamedSharding(four_chips, P()),
+                      NamedSharding(four_chips, P("cfg")),
+                      4 * GATHER_LANES)).compile()
+    hlo = compiled.as_text()
     assert not [c for c in COLLECTIVES if c in hlo]
 
 

@@ -3,7 +3,7 @@
 Prints ``name,us_per_call,derived`` CSV rows.  For the characterization tables
 (3-9) `derived` is the max relative error vs the published cells; for the
 scalability figures (4-10) it is the modeled speedup; for kernels it is
-throughput; for the roofline it is the dominant term + roofline fraction.
+throughput.
 """
 from __future__ import annotations
 
@@ -528,26 +528,6 @@ def kernel_microbench():
     return rows
 
 
-def roofline_table():
-    path = os.path.join(os.path.dirname(__file__), "..", "results",
-                        "dryrun.jsonl")
-    if not os.path.exists(path):
-        return [("roofline", 0.0, "results/dryrun.jsonl missing")]
-    rows = {}
-    for line in open(path):
-        r = json.loads(line)
-        rows[(r["arch"], r["shape"], r["mesh"])] = r
-    out = []
-    for (arch, shape, mesh), r in sorted(rows.items()):
-        if mesh != "16x16":
-            continue
-        rl = r["roofline"]
-        tmax = max(rl["t_compute_s"], rl["t_memory_s"], rl["t_collective_s"])
-        out.append((f"roofline_{arch}_{shape}", 0.0,
-                    f"bound={rl['bound']}|t={tmax:.3f}s|frac={rl['roofline_fraction']:.3f}"))
-    return out
-
-
 # Pre-PR-9 scalar baselines (ns): the retired SCALAR_BASELINE_MULT model's
 # per-app runtimes, frozen so `--scalar` can report old-vs-new drift across
 # the event-model replacement.
@@ -608,7 +588,7 @@ def main(argv=None) -> None:
     ap.add_argument("--quick", action="store_true",
                     help="smoke mode: characterization + batched figures + "
                          "frontend cross-validation + a small batched-vs-"
-                         "sequential sweep; skips the roofline table.  With --dse: the 384-point "
+                         "sequential sweep.  With --dse: the 384-point "
                          "SPACE_QUICK instead of the 1536-point SPACE_FULL")
     ap.add_argument("--dse", action="store_true",
                     help="design-space exploration rows only: enumerate the "
@@ -699,7 +679,6 @@ def main(argv=None) -> None:
                sweep_llc, sweep_mshr, frontend_crossval,
                lambda: rvv_rows(), lambda: codegen_rows(),
                steady_state_table, scalar_rows, lambda: profile_rows(),
-               roofline_table,
                lambda: sweep_wallclock(quick=False))
     print("name,us_per_call,derived")
     for fn in fns:

@@ -2,5 +2,5 @@
 
 Paper: Ramirez et al., "A RISC-V Simulator and Benchmark Suite for Designing
 and Evaluating Vector Architectures", ACM TACO 17(4), 2020.
-See DESIGN.md for the TPU adaptation and EXPERIMENTS.md for results.
+See README.md for usage and docs/architecture.md for the design.
 """

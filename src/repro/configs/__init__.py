@@ -1,3 +1,1 @@
-from repro.configs.base import (ARCH_IDS, SHAPES, InputShape, ModelConfig,
-                                get_config, iter_cells, list_configs, register,
-                                shape_applicable)
+"""Design-space configurations of the vector engine (``vector_engine``)."""

@@ -1,3 +1,1 @@
-# The paper's primary contribution — implement the SYSTEM here
-# (scheduler, optimizer, data path, serving loop, etc.) in the
-# host framework. Add sibling subpackages for substrates.
+"""The simulator: trace sources, the timing engine, the suite, DSE and search."""

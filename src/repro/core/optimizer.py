@@ -1,7 +1,7 @@
-"""AdamW optimizer + LR schedules, pure JAX, shard-friendly.
+"""AdamW optimizer + LR schedules, pure JAX: the surrogate's full-batch trainer.
 
-Moments are fp32 and share the parameter sharding (FSDP keeps optimizer state
-distributed).  Global-norm clipping and decoupled weight decay included.
+Moments are fp32 and shaped like the parameters.  Global-norm clipping and
+decoupled weight decay included.
 """
 from __future__ import annotations
 

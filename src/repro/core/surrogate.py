@@ -19,7 +19,7 @@ The contract, in three parts:
 * **Training** (:func:`fit`): rows mined from a ``ResultCache`` by
   ``ResultCache.export_training_rows`` (a pure join — no re-simulation),
   log-runtime targets, AdamW + cosine LR from the repo's own
-  ``repro.train.optimizer``, the whole step loop fused into one jitted
+  ``repro.core.optimizer``, the whole step loop fused into one jitted
   ``lax.scan``.
 * **Inference** (:class:`SpaceScorer`): flat design-space indices are decoded
   (mixed radix, matching ``DesignSpace.config_at``), featurized and scored
@@ -50,8 +50,7 @@ import jax
 import jax.numpy as jnp
 
 from repro.core import engine as eng
-from repro.core import isa, tracegen
-from repro.train import optimizer
+from repro.core import isa, optimizer, tracegen
 
 _CFG_FIELDS = _dc_fields(eng.VectorEngineConfig)
 
@@ -248,7 +247,7 @@ def fit(rows, hidden: int = 64, steps: int = 1500, lr: float = 3e-3,
     Targets are ``log(runtime_ns)`` (runtimes span ~4 decades across the
     suite; the log makes the MSE a *relative*-error objective).  The whole
     optimization — AdamW with global-norm clipping and warmup+cosine LR from
-    ``repro.train.optimizer`` — runs as one jitted ``lax.scan`` over
+    ``repro.core.optimizer`` — runs as one jitted ``lax.scan`` over
     full-batch gradient steps, so training ~15k rows takes seconds.
     Deterministic in (rows, hyperparameters, seed).
     """

@@ -1,8 +1,8 @@
 """Flash-decode Pallas kernel: one query token vs a long KV cache.
 
 Grid over KV blocks with online-softmax scratch; the valid-length mask makes
-it usable against partially-filled caches.  This is the per-shard compute of
-distributed/collectives.flash_decode_attention, moved from XLA into VMEM.
+it usable against partially-filled caches.  ``ref.decode_attention`` is
+its pure-jnp oracle.
 """
 from __future__ import annotations
 

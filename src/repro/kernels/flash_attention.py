@@ -1,9 +1,8 @@
 """Causal flash attention Pallas kernel (fwd): online softmax, VMEM tiles.
 
-The hillclimbed replacement for models/layers._chunked_attn: scores never
-leave VMEM (the XLA baseline spills [Sq, ck]-sized f32 tensors to HBM — the
-dominant memory-roofline term measured in the dry-run).  Block shapes are
-MXU-aligned (multiples of 128 on the contracted dims).
+Scores never leave VMEM (a chunked XLA attention spills [Sq, ck]-sized f32
+tensors to HBM).  Block shapes are MXU-aligned (multiples of 128 on the
+contracted dims).  ``ref.flash_attention`` is its pure-jnp oracle.
 """
 from __future__ import annotations
 

@@ -1,8 +1,9 @@
 """Pure-jnp oracles for every Pallas kernel (the ``ref`` side of allclose tests).
 
 These are the RiVec suite apps (paper §4) re-expressed as array programs, plus
-the LM hot-spot kernels.  Each function is the semantic ground truth the
-corresponding ``pallas_call`` kernel must reproduce.
+the attention and state-space kernels of the ML workloads.  Each function is
+the semantic ground truth the corresponding ``pallas_call`` kernel must
+reproduce.
 """
 from __future__ import annotations
 
@@ -128,10 +129,56 @@ def decode_attention(q, k, v, kv_len):
     return jnp.einsum("bhk,bkhd->bhd", a.astype(q.dtype), v)
 
 
+def _ssd_chunked(x, dt, A, Bm, Cm, D_skip, chunk):
+    """SSD core.  x [B,S,H,P]; dt [B,S,H]; A [H]; Bm/Cm [B,S,N].
+
+    Returns y [B,S,H,P] and final state [B,H,P,N].
+    """
+    Bsz, S, H, P = x.shape
+    N = Bm.shape[-1]
+    Q = min(chunk, S)
+    assert S % Q == 0, (S, Q)
+    nc = S // Q
+    f32 = jnp.float32
+
+    dA = dt.astype(f32) * A.astype(f32)                      # [B,S,H] (negative)
+    xd = x.astype(f32) * dt.astype(f32)[..., None]           # dt-weighted input
+    # chunked views, scan axis leading
+    rs = lambda t, d: t.reshape((Bsz, nc, Q) + t.shape[2:]).swapaxes(0, 1)
+    dAc, xc = rs(dA, 3), rs(xd, 4)
+    Bc, Cc = rs(Bm.astype(f32), 3), rs(Cm.astype(f32), 3)
+
+    tri = jnp.tril(jnp.ones((Q, Q), f32))
+    idx = jnp.arange(Q)
+
+    def body(state, ch):
+        dAq, xq, Bq, Cq = ch                                  # [B,Q,H], [B,Q,H,P], [B,Q,N]
+        seg = jnp.cumsum(dAq, axis=1)                         # [B,Q,H]
+        # intra-chunk: scores[t,u] = (C_t.B_u) * exp(seg_t - seg_u) for u<=t
+        diff = seg[:, :, None] - seg[:, None, :, :]           # [B,Q,Q,H]
+        diff = jnp.where(tri[None, :, :, None] > 0, diff, -jnp.inf)  # mask pre-exp
+        decay = jnp.exp(diff)
+        cb = jnp.einsum("btn,bun->btu", Cq, Bq)               # [B,Q,Q]
+        y_intra = jnp.einsum("btu,btuh,buhp->bthp", cb, decay, xq)
+        # contribution of carried-in state: y_state[t] = exp(seg_t) * C_t . state
+        y_state = jnp.einsum("btn,bhpn,bth->bthp", Cq, state, jnp.exp(seg))
+        # chunk end state: state' = exp(seg_Q) * state + sum_u exp(seg_Q-seg_u) B_u x_u
+        tot = seg[:, -1]                                      # [B,H]
+        sdecay = jnp.exp(tot[:, None] - seg)                  # [B,Q,H]
+        state_new = (jnp.exp(tot)[:, :, None, None] * state
+                     + jnp.einsum("bun,buhp,buh->bhpn", Bq, xq, sdecay))
+        return state_new, y_intra + y_state
+
+    state0 = jnp.zeros((Bsz, H, P, N), f32)
+    state, yc = jax.lax.scan(body, state0, (dAc, xc, Bc, Cc))
+    y = yc.swapaxes(0, 1).reshape(Bsz, S, H, P)
+    y = y + x.astype(f32) * D_skip.astype(f32)[None, None, :, None]
+    return y.astype(x.dtype), state
+
+
 def ssd_scan(x, dt, A, B, C, chunk):
-    """Mamba-2 SSD reference (same math as models/ssm._ssd_chunked).
+    """Mamba-2 SSD reference: the chunked SSD algorithm with no skip term.
 
     x [b,S,H,P]; dt [b,S,H]; A [H]; B/C [b,S,N] -> y [b,S,H,P]."""
-    from repro.models.ssm import _ssd_chunked
     y, _ = _ssd_chunked(x, dt, A, B, C, jnp.zeros(x.shape[2]), chunk)
     return y

@@ -1,13 +1,10 @@
-"""Serving layers: LLM continuous batching (`engine`) and
-simulation-as-a-service over the vector-engine timing model
+"""Simulation-as-a-service over the vector-engine timing model
 (`sim_service`).
 
-Submodules are imported lazily so ``python -m repro.serve.sim_service``
-doesn't double-import the module it is executing, and importing one layer
-doesn't pay for the other.
+The submodule is imported lazily so ``python -m repro.serve.sim_service``
+doesn't double-import the module it is executing.
 """
 _EXPORTS = {
-    "Request": "engine", "ServeEngine": "engine", "serve_batch": "engine",
     "Arrival": "sim_service", "ServeReport": "sim_service",
     "SimRequest": "sim_service", "SimResult": "sim_service",
     "SimService": "sim_service", "poisson_arrivals": "sim_service",

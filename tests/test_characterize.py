@@ -42,11 +42,11 @@ def test_canneal_avg_vl():
     assert abs(ch.characterize("canneal", 256).avg_vl - 65.41) < 2.0
 
 
-def test_pct_vectorization_increases_with_mvl():
-    for app in ch.PAPER_TABLES:
-        a = ch.characterize(app, 8).pct_vectorization
-        b = ch.characterize(app, 256).pct_vectorization
-        assert b >= a, app
+@pytest.mark.parametrize("app", sorted(ch.PAPER_TABLES))
+def test_pct_vectorization_increases_with_mvl(app):
+    a = ch.characterize(app, 8).pct_vectorization
+    b = ch.characterize(app, 256).pct_vectorization
+    assert b >= a, app
 
 
 # --------------------------------------------------------------------------

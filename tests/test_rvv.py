@@ -299,24 +299,27 @@ def test_corpus_crossval_reference_configs():
     assert exact >= {"flash_attention", "decode_attention", "ssd_scan"}
 
 
-def test_asm_chunk_counts_match_characterized_closed_forms():
-    for app in (a for a in tracegen.APPS if tracegen.APPS[a].asm):
-        for mvl in (8, 64, 256):
-            cfg = eng.VectorEngineConfig(mvl=mvl, lanes=4)
-            eff = suite.effective_mvl(app, cfg)
-            got = rvv.asm_chunks(app, eff, cfg)
-            want = tracegen.APPS[app].chunks(eff)
-            assert abs(got - want) / want < 1e-6, (app, mvl, got, want)
+APPS_WITH_ASM = sorted(a for a in tracegen.APPS if tracegen.APPS[a].asm)
 
 
-def test_corpus_bodies_pass_isa_invariants():
+@pytest.mark.parametrize("app", APPS_WITH_ASM)
+def test_asm_chunk_counts_match_characterized_closed_forms(app):
+    for mvl in (8, 64, 256):
+        cfg = eng.VectorEngineConfig(mvl=mvl, lanes=4)
+        eff = suite.effective_mvl(app, cfg)
+        got = rvv.asm_chunks(app, eff, cfg)
+        want = tracegen.APPS[app].chunks(eff)
+        assert abs(got - want) / want < 1e-6, (app, mvl, got, want)
+
+
+@pytest.mark.parametrize("app", APPS_WITH_ASM)
+def test_corpus_bodies_pass_isa_invariants(app):
     """Satellite: every decoded corpus body satisfies the trace invariants
     (registers in range, vl <= mvl, no dangling sources given the
     prologue definitions)."""
-    for app in (a for a in tracegen.APPS if tracegen.APPS[a].asm):
-        cfg = eng.VectorEngineConfig(mvl=64, lanes=4)
-        d = rvv.decode_app(app, suite.effective_mvl(app, cfg), cfg)
-        assert d.validate() == [], app
+    cfg = eng.VectorEngineConfig(mvl=64, lanes=4)
+    d = rvv.decode_app(app, suite.effective_mvl(app, cfg), cfg)
+    assert d.validate() == [], app
 
 
 def test_asm_variant_rides_the_batched_sweep():

@@ -41,12 +41,11 @@ def test_raw_chain_latency():
     assert _cycles(seg) == 1024.0 / 2
 
 
-def test_issue_width_monotonic():
-    for app in sorted(tracegen.APPS):
-        t = {w: sp.scalar_runtime_ns(app,
-                                     eng.VectorEngineConfig(issue_width=w))
-             for w in (1, 2, 4)}
-        assert t[1] > t[2] >= t[4], (app, t)
+@pytest.mark.parametrize("app", sorted(tracegen.APPS))
+def test_issue_width_monotonic(app):
+    t = {w: sp.scalar_runtime_ns(app, eng.VectorEngineConfig(issue_width=w))
+         for w in (1, 2, 4)}
+    assert t[1] > t[2] >= t[4], (app, t)
 
 
 def test_branch_penalty_monotonic():
@@ -57,11 +56,10 @@ def test_branch_penalty_monotonic():
         assert t[2.0] < t[6.0] < t[20.0], (app, t)
 
 
-def test_fusion_saves_issue_slots():
-    for app in sorted(tracegen.APPS):
-        assert sp.scalar_runtime_ns(
-            app, eng.VectorEngineConfig(fusion=True)) \
-            < sp.scalar_runtime_ns(app), app
+@pytest.mark.parametrize("app", sorted(tracegen.APPS))
+def test_fusion_saves_issue_slots(app):
+    assert sp.scalar_runtime_ns(app, eng.VectorEngineConfig(fusion=True)) \
+        < sp.scalar_runtime_ns(app), app
 
 
 def test_batched_matches_sequential_bitwise():
@@ -74,14 +72,14 @@ def test_batched_matches_sequential_bitwise():
         [sp.scalar_runtime_ns(a, c) for a, c in zip(apps, cfgs)]
 
 
-def test_implied_cpi_is_physical():
+@pytest.mark.parametrize("app", sorted(tracegen.APPS))
+def test_implied_cpi_is_physical(app):
     """Acceptance: no app's scalar baseline implies CPI < 0.5 (the old
     particlefilter 0.104 multiplier implied ~5 IPC on a dual-issue core)."""
-    for app in sorted(tracegen.APPS):
-        prof = tracegen.scalar_profile_for(app)
-        n = tracegen.app_for(app).counts(8).scalar_code_total \
-            * prof.roi_instr_fraction
-        assert sp.scalar_cycles(app) / n >= 0.5, app
+    prof = tracegen.scalar_profile_for(app)
+    n = tracegen.app_for(app).counts(8).scalar_code_total \
+        * prof.roi_instr_fraction
+    assert sp.scalar_cycles(app) / n >= 0.5, app
 
 
 def test_event_breakdown_sums_to_cycles():

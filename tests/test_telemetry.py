@@ -183,17 +183,16 @@ def test_steady_state_with_util():
     assert 0.0 <= rich[0]["vmu_util"] <= 1.0 + 1e-6
 
 
-def test_dep_scalar_attribution_matches_table2():
+@pytest.mark.parametrize("app", sorted(tracegen.APPS))
+def test_dep_scalar_attribution_matches_table2(app):
     """Coupling cycles (dep_scalar) surface for exactly the scalar-
     communication apps of the paper's Table 2."""
     scalar_comm = {"canneal", "particlefilter", "streamcluster",
                    "flash_attention", "decode_attention"}
-    for app in sorted(tracegen.APPS):
-        body = tracegen.body_for(app, suite.effective_mvl(app, CFG_REF),
-                                 CFG_REF)
-        prof = eng.simulate(body.tile(8), CFG_REF, collect_stats=True)
-        has = prof["stalls"]["dep_scalar"] > 0
-        assert has == (app in scalar_comm), (app, prof["stalls"]["dep_scalar"])
+    body = tracegen.body_for(app, suite.effective_mvl(app, CFG_REF), CFG_REF)
+    prof = eng.simulate(body.tile(8), CFG_REF, collect_stats=True)
+    has = prof["stalls"]["dep_scalar"] > 0
+    assert has == (app in scalar_comm), (app, prof["stalls"]["dep_scalar"])
 
 
 # --------------------------------------------------------------------------

@@ -187,15 +187,43 @@ N_STALL = len(STALL_KINDS)
 _S = {k: i for i, k in enumerate(STALL_KINDS)}
 
 
+# The step's indexed state is tiny (32 registers, MAX_RING ring slots, four
+# FU classes), and under vmap an index that differs per lane makes
+# ``arr[idx]`` a gather and ``arr.at[idx].set`` a scatter, which the TPU
+# serialises lane by lane.  These accessors read and write by one-hot
+# compare-and-select over the array's static length instead: plain vector
+# work, and the same values bit for bit.
+def _read(arr, idx):
+    """``arr[idx]``, or 0.0 where ``idx`` lies outside ``arr``.  Every entry
+    the step reads is a time >= 0.0, so the max over the selected entry and
+    zeros is that entry bitwise."""
+    hit = jnp.arange(arr.shape[-1]) == idx
+    return jnp.max(jnp.where(hit, arr, 0.0), axis=-1)
+
+
+def _write(arr, idx, value, write):
+    """``arr`` with ``arr[idx] = value`` where ``write`` holds, else ``arr``
+    unchanged; an ``idx`` outside ``arr`` writes nothing."""
+    return jnp.where((jnp.arange(arr.shape[-1]) == idx) & write, value, arr)
+
+
+def _lookup(table, idx):
+    """``table[idx]`` of a small constant table, ``idx`` in its range."""
+    out = jnp.float32(table[0])
+    for k in range(1, len(table)):
+        out = jnp.where(idx == k, jnp.float32(table[k]), out)
+    return out
+
+
 def _ring_read(ring, count, capacity):
     """Time at which the slot for the `count`-th allocation frees (0 if never
     yet full): value written `capacity` allocations ago."""
-    idx = jnp.mod(count - capacity, MAX_RING)
-    return jnp.where(count >= capacity, ring[idx], 0.0)
+    return jnp.where(count >= capacity,
+                     _read(ring, jnp.mod(count - capacity, MAX_RING)), 0.0)
 
 
-def _ring_write(ring, count, value):
-    return ring.at[jnp.mod(count, MAX_RING)].set(value)
+def _ring_write(ring, count, value, write):
+    return _write(ring, jnp.mod(count, MAX_RING), value, write)
 
 
 def _make_step(params, collect: bool = False):
@@ -217,9 +245,6 @@ def _make_step(params, collect: bool = False):
      mem_ports, lat_l1, lat_l2, lat_dram, scalar_scale, dispatch_lat,
      ooo_f, ring_f, l1_kb, l2_kb, mshrs_f, dram_line_cyc,
      bmiss_extra, fuse_save) = params
-    sc_cost = jnp.asarray(SCALAR_CYCLES)
-    pipe_depth = jnp.asarray(VEC_PIPE_DEPTH)
-    elem_cost = jnp.asarray(VEC_ELEM_CYCLES)
 
     def step(carry, x):
         if collect:
@@ -245,13 +270,13 @@ def _make_step(params, collect: bool = False):
         # exactly 0.0 at the Table-10 defaults (bitwise-neutral).
         t_wait = jnp.where(dep, jnp.maximum(t_scalar, scalar_res), t_scalar)
         s_cf = s_count.astype(jnp.float32)
-        eff_cost = sc_cost[fu] * (1.0 - fuse_save * (fu == 0))
+        eff_cost = _lookup(SCALAR_CYCLES, fu) * (1.0 - fuse_save * (fu == 0))
         sc_time = s_cf * eff_cost * scalar_scale + s_cf * bmiss_extra
         t_scalar_s = t_wait + sc_time
 
         # ---- vector instruction --------------------------------------------
         # scalar pipe cost of carrying the vector instruction to commit
-        t_scalar_v = t_scalar + sc_cost[0] * scalar_scale
+        t_scalar_v = t_scalar + jnp.float32(SCALAR_CYCLES[0]) * scalar_scale
         rob_slot = _ring_read(rob_ring, n_rob, rob_entries)
         phys_slot = _ring_read(phys_ring, n_phys, phys_extra)
         is_mem = (kind == isa.VLOAD) | (kind == isa.VSTORE)
@@ -261,8 +286,9 @@ def _make_step(params, collect: bool = False):
         dispatch = jnp.maximum(jnp.maximum(t_scalar_v + dispatch_lat, rob_slot),
                                jnp.maximum(phys_slot, q_slot))
 
-        r1 = jnp.where(src1 >= 0, reg_ready[jnp.maximum(src1, 0)], 0.0)
-        r2 = jnp.where(src2 >= 0, reg_ready[jnp.maximum(src2, 0)], 0.0)
+        # a source of -1 (none) reads 0.0
+        r1 = _read(reg_ready, src1)
+        r2 = _read(reg_ready, src2)
         ops_ready = jnp.maximum(r1, r2)
 
         fu_free = jnp.where(is_mem, vmu_free, lane_free)
@@ -271,16 +297,17 @@ def _make_step(params, collect: bool = False):
         issue = jnp.where(ooo_f > 0, issue, jnp.maximum(issue, inorder))
 
         # start-up: pipe depth + VRF read-port serialization (§3.2.4)
-        startup = pipe_depth[fu] + jnp.ceil(
+        depth = _lookup(VEC_PIPE_DEPTH, fu)
+        startup = depth + jnp.ceil(
             n_src.astype(jnp.float32) / read_ports)
 
         per_lane = jnp.ceil(vlf / lanes)
-        exec_arith = per_lane * elem_cost[fu]
+        exec_arith = per_lane * _lookup(VEC_ELEM_CYCLES, fu)
         # slides move each element one lane over: one extra hop either topology
         exec_slide = per_lane + 1.0
         hops = jnp.where(ring_f > 0, lanes - 1.0,
                          jnp.ceil(jnp.log2(jnp.maximum(lanes, 2.0))))
-        exec_reduce = per_lane + hops + pipe_depth[fu]
+        exec_reduce = per_lane + hops + depth
         exec_move = per_lane
         exec_mask = per_lane + hops  # vfirst/vpopc reduce a mask to a scalar
 
@@ -306,17 +333,13 @@ def _make_step(params, collect: bool = False):
         t_scalar_n = jnp.where(is_scalar, t_scalar_s, t_scalar_v)
         upd = lambda old, new: jnp.where(is_scalar, old, new)
 
-        reg_ready_n = jnp.where(
-            is_scalar | (dst < 0), reg_ready,
-            reg_ready.at[jnp.maximum(dst, 0)].set(complete))
-        rob_ring_n = jnp.where(is_scalar, rob_ring,
-                               _ring_write(rob_ring, n_rob, commit))
-        phys_ring_n = jnp.where(is_scalar, phys_ring,
-                                _ring_write(phys_ring, n_phys, commit))
-        aq_ring_n = jnp.where(is_scalar | is_mem, aq_ring,
-                              _ring_write(aq_ring, n_aq, issue))
-        mq_ring_n = jnp.where(is_scalar | ~is_mem, mq_ring,
-                              _ring_write(mq_ring, n_mq, issue))
+        is_vec = ~is_scalar
+        # a dst of -1 (none) writes nothing
+        reg_ready_n = _write(reg_ready, dst, complete, is_vec)
+        rob_ring_n = _ring_write(rob_ring, n_rob, commit, is_vec)
+        phys_ring_n = _ring_write(phys_ring, n_phys, commit, is_vec)
+        aq_ring_n = _ring_write(aq_ring, n_aq, issue, is_vec & ~is_mem)
+        mq_ring_n = _ring_write(mq_ring, n_mq, issue, is_vec & is_mem)
         one = jnp.int32(1)
         carry_n = (
             reg_ready_n, rob_ring_n, upd(n_rob, n_rob + one),

@@ -5,6 +5,7 @@ is timing-neutral), so agreement is expected to be bitwise; the asserts allow
 1e-5 relative slack for XLA fusion differences, far inside the 1e-3 the
 reproduction tolerates.
 """
+import jax
 import jax.numpy as jnp
 import numpy as np
 import pytest
@@ -278,3 +279,116 @@ def test_device_gather_matches_a_host_gather_bitwise(mix, monkeypatch):
     monkeypatch.setattr(eng, "_ONE_DEVICE",
                         eng._ONE_DEVICE._replace(gather=_host_gather))
     assert both() == on_device
+
+
+# ---- the step's indexed reads and writes ----------------------------------
+# The step reads and writes its rings, register table and FU cost tables by
+# one-hot compare-and-select (``engine._read``, ``_write``, ``_lookup``).
+# These are the gather and scatter forms they replaced.
+
+def _gather_read(arr, idx):
+    """``arr[idx]`` by a gather, 0.0 for an index below 0."""
+    return jnp.where(idx >= 0, arr[jnp.maximum(idx, 0)], 0.0)
+
+
+def _scatter_write(arr, idx, value, write):
+    """A scatter of ``value`` at ``idx``, then a select of the whole array."""
+    return jnp.where(write & (idx >= 0),
+                     arr.at[jnp.maximum(idx, 0)].set(value), arr)
+
+
+def _gather_lookup(table, idx):
+    return jnp.asarray(table)[idx]
+
+
+STEP_LANES = 64
+
+
+def _step_lanes(seed=18):
+    """``STEP_LANES`` lanes of two chunks of random records: every kind
+    (NOPs as padding writes them), every FU class, register fields of -1,
+    dependent scalar blocks; configs whose ROB, queue and rename
+    capacities run from 1 to ``MAX_RING``."""
+    rng = np.random.default_rng(seed)
+    shape = (STEP_LANES, 2 * eng.CHUNK)
+    kind = rng.integers(isa.SCALAR_BLOCK, isa.NOP + 1, shape)
+    nop = kind == isa.NOP
+    scalar = kind == isa.SCALAR_BLOCK
+    reg = lambda: np.where(nop | (rng.random(shape) < 0.2), -1,
+                           rng.integers(0, isa.N_ARCH_REGS, shape))
+    fields = dict(
+        kind=kind, vl=np.where(scalar | nop, 0, rng.integers(1, 257, shape)),
+        fu=np.where(nop, isa.FU_SIMPLE,
+                    rng.integers(0, isa.N_FU_CLASSES, shape)),
+        n_src=np.where(nop, 0, rng.integers(0, 4, shape)),
+        src1=reg(), src2=reg(), dst=reg(),
+        mem_pattern=rng.integers(isa.MEM_UNIT, isa.MEM_INDEXED + 1, shape),
+        footprint_kb=np.where(nop, 0.0, rng.uniform(1.0, 4096.0, shape)),
+        scalar_count=np.where(scalar, rng.integers(0, 40, shape), 0),
+        dep_scalar=scalar & (rng.random(shape) < 0.5))
+    xs = tuple(np.asarray(fields[f], getattr(isa.nop_trace(1), f).dtype)
+               for f in eng._TRACE_FIELDS)
+    cfgs = [eng.VectorEngineConfig(
+        mvl=256, lanes=int(rng.choice([1, 2, 4, 8])),
+        rob_entries=int(rng.choice([1, 8, 40, eng.MAX_RING])),
+        queue_entries=int(rng.choice([1, 4, 16, eng.MAX_RING])),
+        phys_regs=32 + int(rng.choice([1, 8, 33, eng.MAX_RING])),
+        ooo_issue=bool(i % 2), interconnect=("ring", "crossbar")[i // 2 % 2],
+        vrf_read_ports=1 + i % 3 // 2, mshrs=int(rng.choice([1, 16])),
+        l2_kb=int(rng.choice([256, 1024])), fusion=bool(i % 5 == 0),
+        branch_miss_penalty=float(rng.choice([6.0, 12.0])))
+        for i in range(STEP_LANES)]
+    params = tuple(np.stack(col) for col in
+                   zip(*(eng._cfg_params_np(c) for c in cfgs)))
+    return xs, params
+
+
+def _chunked_carry(program, xs, params):
+    """Every carry field after both chunks, the carry threaded through."""
+    carry = jax.tree.map(lambda a: jnp.zeros((STEP_LANES,) + a.shape, a.dtype),
+                         eng._init_carry())
+    for i in range(2):
+        carry = program(carry, tuple(a[:, i * eng.CHUNK:(i + 1) * eng.CHUNK]
+                                     for a in xs), params)
+    return carry
+
+
+@pytest.mark.parametrize("collect", [False, True])
+def test_one_hot_step_matches_gather_scatter_bitwise(collect, monkeypatch):
+    """The step's one-hot reads and writes give every carry field (and, in
+    the collect_stats scan, every accumulator and timeline output) of the
+    gather and scatter forms bitwise, rings wrapped many times over."""
+    xs, params = _step_lanes()
+    if collect:
+        run = lambda: jax.jit(jax.vmap(eng._profile_core))(xs, params)
+        got = run()
+    else:
+        got = _chunked_carry(eng._chunk_batch_jit, xs, params)
+        for n in (got[2], got[4], got[6], got[8]):  # ROB, rename, AQ, MQ
+            assert int(n.min()) >= 2 * eng.MAX_RING
+        run = lambda: _chunked_carry(jax.jit(jax.vmap(eng._chunk_core)),
+                                     xs, params)
+    monkeypatch.setattr(eng, "_read", _gather_read)
+    monkeypatch.setattr(eng, "_write", _scatter_write)
+    monkeypatch.setattr(eng, "_lookup", _gather_lookup)
+    want = run()
+    got, want = jax.tree.leaves(got), jax.tree.leaves(want)
+    assert len(got) == len(want)
+    for i, (g, w) in enumerate(zip(got, want)):
+        assert np.array_equal(np.asarray(g), np.asarray(w)), i
+
+
+def test_chunk_program_has_no_gather_or_scatter():
+    """The batched chunk scan at a study's batch of 2,048 lanes reads and
+    writes its indexed state without a gather or a scatter."""
+    body = isa.nop_trace(1)
+    sds = lambda shape, a: jax.ShapeDtypeStruct(shape, np.asarray(a).dtype)
+    carry = tuple(sds((2048,) + a.shape, a) for a in eng._init_carry())
+    xs = tuple(sds((2048, eng.CHUNK), getattr(body, f))
+               for f in eng._TRACE_FIELDS)
+    params = tuple(sds((2048,), p) for p in
+                   eng._cfg_params_np(eng.VectorEngineConfig()))
+    text = eng._chunk_batch_jit.lower(carry, xs, params).as_text()
+    assert "stablehlo.while" in text
+    assert "stablehlo.gather" not in text
+    assert "stablehlo.scatter" not in text
